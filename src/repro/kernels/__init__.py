@@ -1,13 +1,15 @@
 """Pallas TPU kernels for SeDA's perf-critical compute.
 
 Each kernel package has kernel.py (pl.pallas_call + BlockSpec VMEM
-tiling), ops.py (jit'd public wrappers) and ref.py (pure-jnp oracle).
-All are validated in interpret mode against their oracles, which chain
-back to FIPS-197 test vectors for everything AES-derived.
+tiling over word planes, see :mod:`repro.kernels.common`) and ref.py
+(pure-jnp oracle); ``fused_crypt_mac/ops.py`` composes them into the
+page crossing.  Tests run them in interpret mode on CPU against their
+oracles, which chain back to FIPS-197 test vectors for everything
+AES-derived; on a TPU Mosaic compiles them.
 
-- aes_ctr        — AES-128-CTR keystream ("AES Engine"); SubBytes via
-                   table gather or MXU one-hot matmul
-- otp_xor        — fused B-AES diversify + data XOR ("Crypt Engine")
-- xormac         — NH universal hash for optBlk MACs ("Integ Engine")
-- fused_crypt_mac — beyond-paper single-pass decrypt + hash
+- aes_ctr         — AES-128-CTR keystream ("AES Engine"); SubBytes as
+                    an in-register lane gather over the S-box halves
+- fused_crypt_mac — B-AES diversify + pad XOR ("Crypt Engine") and the
+                    NH hash of the optBlk MAC ("Integ Engine") in one
+                    pass, for reads and writes, single or mixed keys
 """

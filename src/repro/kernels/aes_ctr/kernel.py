@@ -1,25 +1,23 @@
 """Pallas TPU kernel: batched AES-128-CTR keystream generation.
 
 This is SeDA's "AES Engine" (paper Fig. 2(b)) mapped to a TPU core.
-One grid program produces the OTPs for ``TILE_N`` counter blocks from
-VMEM-resident state:
+Counter blocks arrive as word planes (see :mod:`repro.kernels.common`):
+one grid step encrypts ``tile * 128`` blocks, held as a (16, tile, 128)
+int32 state — one dense plane per state byte.
 
-  HBM -> VMEM: counter words (TILE_N, 4) u32, round keys (11,16), S-box
-  VMEM compute: 10 unrolled AES rounds over a (TILE_N, 16) int32 state
-               (one byte per int32 lane — VPU-native shifts/xors)
-  VMEM -> HBM: OTP lanes (TILE_N, 4) u32
+  HBM -> VMEM: counter planes (4, tile, 128) u32, S-box halves
+               (2, 1, 128), round keys ((11, 16, 1, 128) byte rows, or
+               per-block (44, tile, 128) u32 word planes for mixed keys)
+  VMEM compute: AES rounds in a loop; ShiftRows reorders the byte
+               planes and MixColumns is shift/xor arithmetic across
+               them, so neither needs a gather
+  VMEM -> HBM: OTP planes (4, tile, 128) u32, little-endian lanes
 
-TPU adaptation of SubBytes (the only non-affine step):
-
-* ``subbytes="take"``   — 256-entry table gather (works everywhere;
-  gathers are serviced by the scalar/vector load units on TPU).
-* ``subbytes="onehot"`` — one-hot(state) @ sbox matmul: a (TILE_N*16,
-  256) f32 one-hot times a (256, 1) table runs on the MXU.  This is the
-  TPU-native analogue of "adding AES engines": bandwidth scales with
-  MXU throughput instead of gather throughput.  Exact because all
-  values are small integers in f32.
-
-Both paths are validated against the FIPS-chained oracle in ref.py.
+SubBytes is the only table lookup.  The 256-entry S-box is split into
+two 128-entry rows and every byte gathers along the lane axis from
+both (``take_along_axis`` on a 2D view — the in-register lane gather
+Mosaic supports), picking by the byte's top bit.  Validated against
+the FIPS-chained oracle in ref.py.
 """
 
 from __future__ import annotations
@@ -30,173 +28,129 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.aes import _RCON_NP, _SBOX_NP, _SHIFT_ROWS_PERM_NP  # noqa: F401
-from repro.kernels.common import cdiv, default_interpret
+from repro.core.aes import _SBOX_NP, _SHIFT_ROWS_PERM_NP
+from repro.kernels import common
+from repro.kernels.common import LANES, plane_rows, plane_spec
 
-__all__ = ["aes_ctr_keystream", "aes_ctr_keystream_multi"]
+__all__ = ["aes_ctr_keystream", "aes_ctr_keystream_multi", "aes_planes"]
 
-def _iota(n: int, dtype=jnp.int32) -> jax.Array:
-    """1D iota built in-kernel (Pallas forbids captured array constants)."""
-    return jax.lax.broadcasted_iota(dtype, (n,), 0)
-
-
-def _unpack_counter_bytes(words_u32: jax.Array) -> jax.Array:
-    """(T, 4) u32 -> (T, 16) i32 byte state (big-endian per word)."""
-    w = words_u32.astype(jnp.uint32)
-    shifts = ((3 - _iota(4)) * 8).astype(jnp.uint32)  # [24, 16, 8, 0]
-    b = w[:, :, None] >> shifts[None, None, :]
-    return (b & jnp.uint32(0xFF)).astype(jnp.int32).reshape(w.shape[0], 16)
-
-
-def _pack_lanes_le(state_i32: jax.Array) -> jax.Array:
-    """(T, 16) i32 byte state -> (T, 4) u32 little-endian lanes."""
-    s = state_i32.astype(jnp.uint32).reshape(state_i32.shape[0], 4, 4)
-    shifts = (_iota(4) * 8).astype(jnp.uint32)  # [0, 8, 16, 24]
-    return jnp.sum(s << shifts[None, None, :], axis=-1, dtype=jnp.uint32)
+# S-box as its two 128-entry halves, one lane row each.
+_SBOX_HALVES_NP = _SBOX_NP.astype("int32").reshape(2, 1, LANES)
 
 
 def _xtime(x: jax.Array) -> jax.Array:
-    """GF(2^8) doubling on int32 byte lanes."""
-    doubled = (x << 1) ^ jnp.where(x & 0x80, 0x1B, 0)
-    return doubled & 0xFF
+    """GF(2^8) doubling on int32 bytes."""
+    return ((x << 1) ^ jnp.where((x & 0x80) != 0, 0x1B, 0)) & 0xFF
+
+
+def _shift_rows(state: jax.Array) -> jax.Array:
+    return jnp.concatenate([state[p:p + 1] for p in _SHIFT_ROWS_PERM_NP])
 
 
 def _mix_columns(state: jax.Array) -> jax.Array:
-    s = state.reshape(state.shape[0], 4, 4)  # (T, col, row)
-    a0, a1, a2, a3 = s[:, :, 0], s[:, :, 1], s[:, :, 2], s[:, :, 3]
+    s = state.reshape((4, 4) + state.shape[1:])       # (col, row, ...)
+    a0, a1, a2, a3 = s[:, 0], s[:, 1], s[:, 2], s[:, 3]
     x0, x1, x2, x3 = _xtime(a0), _xtime(a1), _xtime(a2), _xtime(a3)
-    b0 = x0 ^ (x1 ^ a1) ^ a2 ^ a3
-    b1 = a0 ^ x1 ^ (x2 ^ a2) ^ a3
-    b2 = a0 ^ a1 ^ x2 ^ (x3 ^ a3)
-    b3 = (x0 ^ a0) ^ a1 ^ a2 ^ x3
-    return jnp.stack([b0, b1, b2, b3], axis=-1).reshape(state.shape)
+    out = jnp.stack([x0 ^ (x1 ^ a1) ^ a2 ^ a3,
+                     a0 ^ x1 ^ (x2 ^ a2) ^ a3,
+                     a0 ^ a1 ^ x2 ^ (x3 ^ a3),
+                     (x0 ^ a0) ^ a1 ^ a2 ^ x3], axis=1)
+    return out.reshape(state.shape)
 
 
-def _sub_bytes_take(state: jax.Array, sbox: jax.Array) -> jax.Array:
-    return jnp.take(sbox, state, axis=0)
+def _aes_ctr_kernel(ctr_ref, rk_ref, sbox_ref, out_ref, *, per_block: bool):
+    tile = ctr_ref.shape[1]
+    flat = (16 * tile, LANES)
+    lo = jnp.broadcast_to(sbox_ref[0], flat)
+    hi = jnp.broadcast_to(sbox_ref[1], flat)
+
+    def sub_bytes(state):
+        x = state.reshape(flat)
+        idx = x & 0x7F
+        y = jnp.where(x < 0x80, jnp.take_along_axis(lo, idx, axis=1),
+                      jnp.take_along_axis(hi, idx, axis=1))
+        return y.reshape(state.shape)
+
+    def round_key(r):
+        """Round key ``r`` as (16, 1 | tile, 128) int32 bytes."""
+        if not per_block:
+            return rk_ref[r]
+        # Per-block schedules: 44 little-endian u32 words per block.
+        words = rk_ref[pl.ds(4 * r, 4)]
+        b = jnp.stack([(words >> (8 * i)) & 0xFF for i in range(4)], axis=1)
+        return b.reshape(16, tile, LANES).astype(jnp.int32)
+
+    # Counter words serialize big-endian: byte 4w + i = word w >> 24-8i.
+    words = ctr_ref[...]
+    state = jnp.stack([(words >> (24 - 8 * i)) & 0xFF for i in range(4)],
+                      axis=1).reshape(16, tile, LANES).astype(jnp.int32)
+    state = state ^ round_key(0)
+
+    def full_round(r, state):
+        return _mix_columns(_shift_rows(sub_bytes(state))) ^ round_key(r)
+
+    state = jax.lax.fori_loop(1, 10, full_round, state)
+    state = _shift_rows(sub_bytes(state)) ^ round_key(10)
+    b = state.reshape(4, 4, tile, LANES).astype(jnp.uint32)
+    out_ref[...] = b[:, 0] | (b[:, 1] << 8) | (b[:, 2] << 16) | (b[:, 3] << 24)
 
 
-def _sub_bytes_onehot(state: jax.Array, sbox_f32: jax.Array) -> jax.Array:
-    """SubBytes on the MXU: one-hot(state) @ sbox."""
-    flat = state.reshape(-1)
-    onehot = jax.nn.one_hot(flat, 256, dtype=jnp.float32)
-    looked = onehot @ sbox_f32  # (T*16,)
-    return looked.astype(jnp.int32).reshape(state.shape)
+def aes_planes(ctr: jax.Array, round_keys: jax.Array, *,
+               tile_rows: int = 32,
+               interpret: bool | None = None) -> jax.Array:
+    """AES-128 over counter word planes: (4, rows, 128) u32 -> OTP planes.
 
-
-def _aes_ctr_kernel(counters_ref, rk_ref, sbox_ref, out_ref, *, subbytes: str):
-    state = _unpack_counter_bytes(counters_ref[...])
-    rk = rk_ref[...].astype(jnp.int32)  # (11, 16)
-    if subbytes == "onehot":
-        sbox = sbox_ref[...].astype(jnp.float32)
-        sub = functools.partial(_sub_bytes_onehot, sbox_f32=sbox)
+    ``round_keys`` is one (11, 16) u8 schedule, or per-block schedules
+    as (44, rows, 128) u32 planes of little-endian words.
+    """
+    if interpret is None:
+        interpret = common.default_interpret()
+    rows = ctr.shape[1]
+    tile = min(tile_rows, rows)
+    per_block = round_keys.ndim == 3
+    if per_block:
+        rk, rk_spec = round_keys.astype(jnp.uint32), plane_spec(44, tile)
     else:
-        sbox = sbox_ref[...].astype(jnp.int32)
-        sub = functools.partial(_sub_bytes_take, sbox=sbox)
-    # ShiftRows permutation, built in-kernel: perm[r+4c] = r + 4((c+r)%4).
-    idx = _iota(16)
-    r, c = idx % 4, idx // 4
-    perm = r + 4 * ((c + r) % 4)
-
-    state = state ^ rk[0][None, :]
-    for rnd in range(1, 10):  # unrolled: round keys static-indexed
-        state = sub(state)
-        state = jnp.take(state, perm, axis=1)  # ShiftRows
-        state = _mix_columns(state)
-        state = state ^ rk[rnd][None, :]
-    state = sub(state)
-    state = jnp.take(state, perm, axis=1)
-    state = state ^ rk[10][None, :]
-    out_ref[...] = _pack_lanes_le(state)
+        rk = jnp.broadcast_to(
+            round_keys.astype(jnp.int32)[:, :, None, None], (11, 16, 1, LANES))
+        rk_spec = pl.BlockSpec((11, 16, 1, LANES), lambda i: (0, 0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_aes_ctr_kernel, per_block=per_block),
+        grid=(rows // tile,),
+        in_specs=[plane_spec(4, tile), rk_spec,
+                  pl.BlockSpec((2, 1, LANES), lambda i: (0, 0, 0))],
+        out_specs=plane_spec(4, tile),
+        out_shape=jax.ShapeDtypeStruct((4, rows, LANES), jnp.uint32),
+        interpret=interpret,
+    )(ctr.astype(jnp.uint32), rk, jnp.asarray(_SBOX_HALVES_NP))
 
 
-def _aes_ctr_kernel_multi(counters_ref, rk_ref, sbox_ref, out_ref, *,
-                          subbytes: str):
-    """Per-block key schedules: rk_ref is (T, 11*16) — one schedule per
-    counter block, so one kernel pass serves a mixed-key batch (pages
-    owned by different tenant-epoch bank rows)."""
-    state = _unpack_counter_bytes(counters_ref[...])
-    t = state.shape[0]
-    rk = rk_ref[...].astype(jnp.int32).reshape(t, 11, 16)
-    if subbytes == "onehot":
-        sbox = sbox_ref[...].astype(jnp.float32)
-        sub = functools.partial(_sub_bytes_onehot, sbox_f32=sbox)
-    else:
-        sbox = sbox_ref[...].astype(jnp.int32)
-        sub = functools.partial(_sub_bytes_take, sbox=sbox)
-    idx = _iota(16)
-    r, c = idx % 4, idx // 4
-    perm = r + 4 * ((c + r) % 4)
-
-    state = state ^ rk[:, 0]
-    for rnd in range(1, 10):
-        state = sub(state)
-        state = jnp.take(state, perm, axis=1)
-        state = _mix_columns(state)
-        state = state ^ rk[:, rnd]
-    state = sub(state)
-    state = jnp.take(state, perm, axis=1)
-    state = state ^ rk[:, 10]
-    out_ref[...] = _pack_lanes_le(state)
+@functools.partial(jax.jit, static_argnames=("tile_rows", "interpret"))
+def aes_ctr_keystream(counter_words: jax.Array, round_keys: jax.Array, *,
+                      tile_rows: int = 32,
+                      interpret: bool | None = None) -> jax.Array:
+    """(N, 4) u32 counters + (11, 16) u8 schedule -> (N, 4) u32 OTP lanes."""
+    n = counter_words.shape[0]
+    rows, _ = plane_rows(n, tile_rows)
+    out = aes_planes(common.to_planes(counter_words, rows), round_keys,
+                     tile_rows=tile_rows, interpret=interpret)
+    return common.from_planes(out, n)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_n", "subbytes", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile_rows", "interpret"))
 def aes_ctr_keystream_multi(counter_words: jax.Array,
-                            round_keys_per: jax.Array, *, tile_n: int = 256,
-                            subbytes: str = "take",
+                            round_keys_per: jax.Array, *,
+                            tile_rows: int = 32,
                             interpret: bool | None = None) -> jax.Array:
     """(N, 4) u32 counters + PER-BLOCK (N, 11, 16) u8 schedules ->
     (N, 4) u32 OTP lanes.  Mixed-key sibling of
     :func:`aes_ctr_keystream`; bit-identical to running the single-key
     kernel once per distinct schedule."""
-    if interpret is None:
-        interpret = default_interpret()
     n = counter_words.shape[0]
-    tile_n = min(tile_n, max(8, n))
-    n_pad = cdiv(n, tile_n) * tile_n
-    padded = jnp.zeros((n_pad, 4), jnp.uint32).at[:n].set(counter_words)
-    rk_flat = round_keys_per.reshape(n, 11 * 16)
-    rk_pad = jnp.zeros((n_pad, 11 * 16), jnp.uint8).at[:n].set(rk_flat)
-    sbox = jnp.asarray(_SBOX_NP, jnp.int32)
-
-    out = pl.pallas_call(
-        functools.partial(_aes_ctr_kernel_multi, subbytes=subbytes),
-        grid=(n_pad // tile_n,),
-        in_specs=[
-            pl.BlockSpec((tile_n, 4), lambda i: (i, 0)),
-            pl.BlockSpec((tile_n, 11 * 16), lambda i: (i, 0)),
-            pl.BlockSpec((256,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((tile_n, 4), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 4), jnp.uint32),
-        interpret=interpret,
-    )(padded, rk_pad, sbox)
-    return out[:n]
-
-
-@functools.partial(jax.jit, static_argnames=("tile_n", "subbytes", "interpret"))
-def aes_ctr_keystream(counter_words: jax.Array, round_keys: jax.Array, *,
-                      tile_n: int = 256, subbytes: str = "take",
-                      interpret: bool | None = None) -> jax.Array:
-    """(N, 4) u32 counters + (11, 16) u8 schedule -> (N, 4) u32 OTP lanes."""
-    if interpret is None:
-        interpret = default_interpret()
-    n = counter_words.shape[0]
-    tile_n = min(tile_n, max(8, n))
-    n_pad = cdiv(n, tile_n) * tile_n
-    padded = jnp.zeros((n_pad, 4), jnp.uint32).at[:n].set(counter_words)
-    sbox = jnp.asarray(_SBOX_NP, jnp.int32)
-
-    out = pl.pallas_call(
-        functools.partial(_aes_ctr_kernel, subbytes=subbytes),
-        grid=(n_pad // tile_n,),
-        in_specs=[
-            pl.BlockSpec((tile_n, 4), lambda i: (i, 0)),
-            pl.BlockSpec((11, 16), lambda i: (0, 0)),
-            pl.BlockSpec((256,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((tile_n, 4), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 4), jnp.uint32),
-        interpret=interpret,
-    )(padded, round_keys, sbox)
-    return out[:n]
+    rows, _ = plane_rows(n, tile_rows)
+    words = jax.lax.bitcast_convert_type(
+        round_keys_per.astype(jnp.uint8).reshape(n, 44, 4), jnp.uint32)
+    out = aes_planes(common.to_planes(counter_words, rows),
+                     common.to_planes(words, rows),
+                     tile_rows=tile_rows, interpret=interpret)
+    return common.from_planes(out, n)

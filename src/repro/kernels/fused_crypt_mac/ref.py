@@ -1,4 +1,6 @@
-"""Oracle for the fused decrypt+NH kernel: composition of the two refs."""
+"""Oracles for the fused crypt + NH kernels: the crypt half
+(:func:`otp_xor_ref`), the hash half (:func:`nh_hash_ref`) and their
+read/write, single/mixed-key compositions."""
 
 from __future__ import annotations
 
@@ -6,10 +8,34 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import mac
-from repro.kernels.otp_xor.ref import otp_xor_ref
-
-__all__ = ["fused_crypt_mac_ref", "fused_crypt_mac_mixed_ref",
+__all__ = ["otp_xor_ref", "nh_hash_ref", "fused_crypt_mac_ref", "fused_crypt_mac_mixed_ref",
            "fused_crypt_mac_write_ref", "fused_crypt_mac_write_mixed_ref"]
+
+
+def otp_xor_ref(data_lanes: jax.Array, base_otp_lanes: jax.Array,
+                div_lanes: jax.Array) -> jax.Array:
+    """Apply per-segment diversified OTPs to wide blocks.
+
+    Args:
+      data_lanes: (N, S*4) uint32 — N wide blocks, S 16B segments each.
+      base_otp_lanes: (N, 4) uint32 — one base OTP per block (AES output).
+      div_lanes: (S, 4) uint32 — per-segment diversifiers (round keys;
+        row 0 is zero so segment 0 keeps the base OTP).
+
+    Returns (N, S*4) uint32 lanes:
+      out[n, 4s+l] = data[n, 4s+l] ^ base[n, l] ^ div[s, l]
+    """
+    n, lanes = data_lanes.shape
+    s = div_lanes.shape[0]
+    d = data_lanes.reshape(n, s, 4)
+    pads = base_otp_lanes[:, None, :] ^ div_lanes[None, :, :]
+    return (d ^ pads).reshape(n, lanes)
+
+
+def nh_hash_ref(payload_u32: jax.Array, key_u32: jax.Array) -> jax.Array:
+    """(N, L) u32 payload + (L,) u32 key -> (N, 2) u32 (hi, lo)."""
+    hi, lo = mac.nh_hash(payload_u32, key_u32)
+    return jnp.stack([hi, lo], axis=-1)
 
 
 def fused_crypt_mac_ref(ct_lanes: jax.Array, base_otp_lanes: jax.Array,
@@ -28,8 +54,7 @@ def fused_crypt_mac_ref(ct_lanes: jax.Array, base_otp_lanes: jax.Array,
     """
     pt = otp_xor_ref(ct_lanes, base_otp_lanes, div_lanes)
     payload = jnp.concatenate([ct_lanes, bind_words], axis=-1)
-    hi, lo = mac.nh_hash(payload, key_u32)
-    return pt, jnp.stack([hi, lo], axis=-1)
+    return pt, nh_hash_ref(payload, key_u32)
 
 
 def fused_crypt_mac_mixed_ref(ct_lanes: jax.Array, base_otp_lanes: jax.Array,
@@ -60,8 +85,7 @@ def fused_crypt_mac_write_ref(pt_lanes: jax.Array, base_otp_lanes: jax.Array,
     input moves to the pad-XOR output)."""
     ct = otp_xor_ref(pt_lanes, base_otp_lanes, div_lanes)
     payload = jnp.concatenate([ct, bind_words], axis=-1)
-    hi, lo = mac.nh_hash(payload, key_u32)
-    return ct, jnp.stack([hi, lo], axis=-1)
+    return ct, nh_hash_ref(payload, key_u32)
 
 
 def fused_crypt_mac_write_mixed_ref(pt_lanes: jax.Array,

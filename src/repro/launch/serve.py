@@ -29,13 +29,21 @@ scheduler ticks (round-robin), exercising live lazy rotation::
 ``--shards N`` serves through the cluster engine instead: one shard
 engine (and one paged pool, shard-bound RePA/CTR identity included)
 per device, least-loaded routing with tenant affinity, and secure page
-migration under imbalance.  On CPU the N devices are conjured via
-``--xla_force_host_platform_device_count`` (set below, before jax
-initializes)::
+migration under imbalance.  On a host with N accelerators each shard
+owns one of them; on CPU the N devices are conjured via
+``--xla_force_host_platform_device_count`` (set by :func:`main` before
+jax initializes its backends)::
 
     PYTHONPATH=src python -m repro.launch.serve --arch minitron-4b \
         --smoke --engine paged --scheme seda --batch 8 --gen-len 16 \
         --shards 2
+
+``--prompt-len`` takes a comma list for the paged engine, cycled over
+the requests (``--prompt-len 128,512`` mixes short and long prompts).
+
+:func:`main` turns on JAX's persistent compilation cache: the directory
+``JAX_COMPILATION_CACHE_DIR`` names when it is set, otherwise
+``.jax_cache`` at the root of the checkout.
 """
 
 from __future__ import annotations
@@ -47,45 +55,46 @@ import os
 import sys
 import time
 
-# Must run before jax initializes its backends: a --shards run on a
-# single-device host forces that many CPU devices into existence.
-# Both argparse spellings (--shards N and --shards=N) must match here.
-def _sniff_shards(argv) -> int:
-    n = 1
-    for i, arg in enumerate(argv):
-        val = None
-        if arg == "--shards" and i + 1 < len(argv):
-            val = argv[i + 1]
-        elif arg.startswith("--shards="):
-            val = arg.split("=", 1)[1]
-        if val is not None:
-            try:
-                n = int(val)
-            except ValueError:
-                pass
-    return n
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-
-_n = _sniff_shards(sys.argv)
-if _n > 1 and "xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={_n}").strip()
-
-import jax           # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np   # noqa: E402
-
-from repro.checkpoint.secure_ckpt import latest_step, load_checkpoint  # noqa: E402
-from repro.configs import get_arch                     # noqa: E402
-from repro.core.secure_memory import SecureKeys        # noqa: E402
-from repro.models import lm as lm_mod                  # noqa: E402
-from repro.models.layers import init_params, shape_structs  # noqa: E402
-from repro.serve.serve_step import (greedy_sample, make_decode_step,  # noqa: E402
+from repro.checkpoint.secure_ckpt import latest_step, load_checkpoint
+from repro.configs import get_arch
+from repro.core.secure_memory import SecureKeys
+from repro.models import lm as lm_mod
+from repro.models.layers import init_params, shape_structs
+from repro.serve.serve_step import (greedy_sample, make_decode_step,
                                     make_prefill_step)
 
 log = logging.getLogger("repro.serve")
+
+# src/repro/launch/serve.py -> the checkout root.
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and
+    is used as it is.  Otherwise the cache lives at a fixed path in the
+    checkout, so a second run of the same programs finds its compiles.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def _force_host_devices(n: int) -> None:
+    """Ask the CPU backend for ``n`` devices (a no-op for accelerators
+    and once the CPU backend has initialized)."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={n}").strip()
 
 
 class _JsonFormatter(logging.Formatter):
@@ -127,7 +136,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=16)
+    ap.add_argument("--prompt-len", default="16",
+                    help="prompt tokens per request; a comma list is "
+                         "cycled over the requests (--engine paged only)")
     ap.add_argument("--gen-len", type=int, default=16)
     ap.add_argument("--engine", choices=("simple", "paged"), default="simple")
     ap.add_argument("--scheme", default="seda",
@@ -189,7 +200,14 @@ def main(argv=None) -> dict:
                          "(--engine paged only; compiles one decode "
                          "variant per bucket)")
     args = ap.parse_args(argv)
+    args.prompt_lens = [int(x) for x in str(args.prompt_len).split(",")]
+    if args.shards > 1:
+        _force_host_devices(args.shards)
     _setup_logging(args)
+    enable_compile_cache()
+    if len(args.prompt_lens) > 1 and args.engine != "paged":
+        raise SystemExit("a --prompt-len list needs --engine paged (the "
+                         "simple loop decodes one dense batch)")
     if args.tenants and args.engine != "paged":
         raise SystemExit("--tenants needs --engine paged")
     if args.shards and args.engine != "paged":
@@ -229,13 +247,14 @@ def main(argv=None) -> dict:
     if args.engine == "paged":
         return _serve_paged(arch, cfg, params, args)
 
-    max_len = args.prompt_len + args.gen_len
+    prompt_len = args.prompt_lens[0]
+    max_len = prompt_len + args.gen_len
     prefill = jax.jit(make_prefill_step(arch, cfg, max_len))
     decode = jax.jit(make_decode_step(arch, cfg))
 
     rng = np.random.default_rng(args.seed)
     prompts = jnp.asarray(rng.integers(
-        1, cfg.vocab, (args.batch, args.prompt_len), dtype=np.int64)
+        1, cfg.vocab, (args.batch, prompt_len), dtype=np.int64)
         .astype(np.int32))
     logits, caches = prefill(params, {"tokens": prompts})
     tok = greedy_sample(logits)
@@ -259,7 +278,7 @@ def _serve_paged(arch, cfg, params, args) -> dict:
     from repro.serve.engine import SecureServingEngine
 
     pages_per_slot = args.pages_per_slot or -(
-        -(args.prompt_len + args.gen_len) // args.page_tokens)
+        -(max(args.prompt_lens) + args.gen_len) // args.page_tokens)
     n_pages = args.n_pages or args.batch * pages_per_slot
     registry = None
     sessions = []
@@ -313,7 +332,8 @@ def _serve_paged(arch, cfg, params, args) -> dict:
     rng = np.random.default_rng(args.seed)
     rids = []
     for i in range(args.batch):
-        prompt = list(map(int, rng.integers(1, cfg.vocab, args.prompt_len)))
+        prompt_len = args.prompt_lens[i % len(args.prompt_lens)]
+        prompt = list(map(int, rng.integers(1, cfg.vocab, prompt_len)))
         session = sessions[i % len(sessions)] if sessions else None
         rids.append(eng.submit(prompt=prompt, max_new_tokens=args.gen_len,
                                session=session))
@@ -387,7 +407,8 @@ def _serve_paged(arch, cfg, params, args) -> dict:
              "tick) — exiting non-zero")
         raise SystemExit(3)
     return {"tokens": toks, "tok_per_s": rate, "stats": stats,
-            "latency": done.latency}
+            "latency": done.latency, "deferred_mac_ok": bool(mac_ok),
+            "engine": eng}
 
 
 def _run_graceful(eng, *, is_cluster: bool):

@@ -64,7 +64,10 @@ def init_params(specs: Any, key: jax.Array) -> Any:
     leaves, treedef = jax.tree_util.tree_flatten(
         specs, is_leaf=lambda x: isinstance(x, ParamSpec))
     keys = jax.random.split(key, len(leaves))
-    params = [_init_leaf(k, s) for k, s in zip(keys, leaves)]
+    # One jitted program per leaf: the f32 draw fuses into the cast, so
+    # a full-size model never holds an f32 copy of its largest leaf.
+    init = jax.jit(_init_leaf, static_argnums=1)
+    params = [init(k, s) for k, s in zip(keys, leaves)]
     return jax.tree_util.tree_unflatten(treedef, params)
 
 

@@ -47,6 +47,10 @@ ENGINE_COUNTERS = {
     "fused_mixed_ticks": "mixed-row ticks kept on the fused READ kernel",
     "fused_write_ticks": "ticks resealing dirty pages via the fused WRITE "
                          "kernel",
+    "fused_read_ticks": "verifying ticks whose page read ran the fused "
+                        "READ kernel",
+    "reference_read_ticks": "verifying ticks whose page read ran the jnp "
+                            "reference (wide blocks, or kernels off)",
     "decode_bucket_compiles": "(bucket, uniform) decode variants compiled",
     "decode_page_reads": "pages gathered by decode (active slots x bucket)",
     "prefix_hit_pages": "cache pages installed read-only at admission",

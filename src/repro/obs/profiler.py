@@ -5,8 +5,10 @@ bytes-accessed number per compiled decode variant — enough to see that
 a scheme costs *something*, useless for saying *where*.  This module
 walks the compiled HLO text instead (``fn.lower().compile()
 .as_text()``), which on both the CPU and TPU backends keeps per
--instruction ``metadata={op_name=... source_file=... source_line=...}``
-pointing at the Python that built each op.  That lets us split the
+-instruction ``metadata={op_name=... stack_frame_id=N}`` pointing at the
+Python call stack that built each op; the id resolves through the
+``FileNames`` / ``FileLocations`` / ``StackFrames`` tables at the top of
+the module text.  That lets us split the
 decode step's cost into
 
 * **protection** — AES-CTR keystream + BAES key schedule, NH/CBC-MAC,
@@ -63,6 +65,7 @@ _PROTECTION_BASENAMES = frozenset({
 # source-line ranges ast gives us.
 _KV_PROTECTION_FUNCS = frozenset({
     "_block_pa", "_tenant_words", "_shard_ctr_word", "_block_counters",
+    "_counter_words",
     "_block_binding", "_uniform_keys", "_crypt", "_page_block_macs",
     "_fused_crossing", "_fused_read", "_fused_write",
     "deferred_pool_check",
@@ -104,7 +107,43 @@ def classify_source(source_file: str, source_line: int) -> str:
 
 # -- HLO text walking --------------------------------------------------------
 
-_META_RE = re.compile(r'source_file="([^"]+)" source_line=(\d+)')
+_FRAME_ID_RE = re.compile(r"stack_frame_id=(\d+)")
+_TABLE_ROW_RE = re.compile(r'^(\d+) (?:"(.*)"|\{(.*)\})$')
+_FIELD_RE = re.compile(r"(\w+)=(\d+)")
+
+
+def _frame_sources(hlo_text: str) -> dict:
+    """``stack_frame_id`` -> (file, line) of that frame.
+
+    The id names the innermost frame of the op's call stack; its
+    ``FileLocations`` row gives the file and line.  (``parent_frame_id``
+    links outward; the innermost frame is the one the attribution
+    uses, as the old per-instruction ``source_file`` did.)
+    """
+    tables: dict = {}
+    section = None
+    for raw in hlo_text.splitlines():
+        line = raw.strip()
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            section = tables.setdefault(line, {})
+            continue
+        m = _TABLE_ROW_RE.match(line) if section is not None else None
+        if m is None:
+            section = None
+            continue
+        key = int(m.group(1))
+        section[key] = (m.group(2) if m.group(2) is not None
+                        else {k: int(v) for k, v
+                              in _FIELD_RE.findall(m.group(3))})
+    files = tables.get("FileNames", {})
+    locs = tables.get("FileLocations", {})
+    out = {}
+    for fid, frame in tables.get("StackFrames", {}).items():
+        loc = locs.get(frame.get("file_location_id"))
+        if loc is not None and loc.get("file_name_id") in files:
+            out[fid] = (files[loc["file_name_id"]], loc.get("line", 0))
+    return out
 _SHAPE_RE = re.compile(r"\b(?:pred|s8|u8|s16|u16|f16|bf16|s32|u32|f32|s64"
                        r"|u64|f64|c64|c128)\[([0-9,]*)\]")
 _OP_RE = re.compile(r"=\s*(?:\([^)]*\)\s*)?[a-z0-9_\[\],{}\s]*?"
@@ -203,7 +242,8 @@ def attribute_hlo(hlo_text: str) -> dict:
 
     Attribution cascades through three sources, strongest first:
 
-    1. the instruction's own ``metadata={... source_file= ...}``;
+    1. the instruction's own ``metadata={... stack_frame_id=N}``,
+       resolved through the module's stack-frame tables;
     2. the flop-weighted majority source of a fused computation's body
        (for fusion call lines and metadata-less clones inside bodies);
     3. dataflow inheritance — XLA passes (e.g. the expansion of
@@ -216,6 +256,7 @@ def attribute_hlo(hlo_text: str) -> dict:
     ``tests`` keeps it under 5% of total bytes and flops.
     """
     # -- collect one record per instruction ---------------------------------
+    frames = _frame_sources(hlo_text)
     records = []
     for comp, opcode, line in _iter_instructions(hlo_text):
         inner = bool(_INNER_COMP.match(comp))
@@ -230,8 +271,8 @@ def attribute_hlo(hlo_text: str) -> dict:
         if not inner and opcode not in _FREE_OPS:
             nbytes = float(parse_shape_bytes(body))
         flops = _line_flops(body, opcode)
-        meta = _META_RE.search(line)
-        src = (meta.group(1), int(meta.group(2))) if meta else None
+        meta = _FRAME_ID_RE.search(line)
+        src = frames.get(int(meta.group(1))) if meta else None
         callees = _CALLS_RE.findall(line)
         records.append({"comp": comp, "opcode": opcode, "name": name,
                         "operands": operands, "bytes": nbytes,
@@ -461,14 +502,11 @@ def profile_decode(engine, bucket: Optional[int] = None,
     args = engine._decode_analysis_args(bucket)
     compiled = engine._decode_fn_for(bucket, uniform).lower(*args).compile()
     attr = attribute_hlo(compiled.as_text())
-    try:
-        xla = compiled.cost_analysis()
-        if isinstance(xla, (list, tuple)):
-            xla = xla[0] if xla else {}
-        xla = {k: v for k, v in dict(xla or {}).items()
-               if k in ("flops", "bytes accessed")}
-    except Exception:  # noqa: BLE001 - backend-dependent availability
-        xla = {}
+    xla = compiled.cost_analysis()
+    if isinstance(xla, (list, tuple)):
+        xla = xla[0] if xla else {}
+    xla = {k: v for k, v in dict(xla or {}).items()
+           if k in ("flops", "bytes accessed")}
     tick_hist = engine.metrics.histograms.get("tick_seconds")
     p50 = None
     if tick_hist is not None and tick_hist.count:

@@ -312,7 +312,8 @@ class SecureServingEngine(SubmitAPI):
                  max_slots: int = 4, page_tokens: int = 8,
                  pages_per_slot: int = 8, n_pages: Optional[int] = None,
                  keys: Optional[sm.SecureKeys] = None,
-                 use_kernel: bool = False, defer_interval: int = 16,
+                 use_kernel: Optional[bool] = None,
+                 defer_interval: int = 16,
                  eos_id: Optional[int] = None,
                  verify_every_step: bool = True,
                  registry=None, rotate_every: int = 0,
@@ -380,6 +381,14 @@ class SecureServingEngine(SubmitAPI):
         self.onchip_idx = [i for i in range(len(flat))
                            if not paged[i] and not lengths[i]]
         self.n_leaves = len(flat)
+        if use_kernel is None:
+            # The fused Pallas kernels are Mosaic (TPU) kernels: on the
+            # chip they carry the narrow-block B-AES + NH schemes; on
+            # CPU the jnp reference runs unless a caller asks for the
+            # kernels (interpret mode) explicitly.
+            platform = (device.platform if device is not None
+                        else jax.default_backend())
+            use_kernel = platform == "tpu"
         self.spec = kvp.build_page_spec(
             cache_tree, scheme=scheme, page_tokens=page_tokens,
             n_pages=n_pages, max_slots=max_slots, max_len=self.max_len,
@@ -1262,12 +1271,9 @@ class SecureServingEngine(SubmitAPI):
         """
         if bucket is None:
             bucket = self.pages_per_slot
-        try:
-            fn = self._decode_fn_for(bucket)
-            args = self._decode_analysis_args(bucket)
-            cost = fn.lower(*args).compile().cost_analysis()
-        except Exception:  # noqa: BLE001 - backend-dependent availability
-            return {}
+        fn = self._decode_fn_for(bucket)
+        args = self._decode_analysis_args(bucket)
+        cost = fn.lower(*args).compile().cost_analysis()
         if isinstance(cost, (list, tuple)):
             cost = cost[0] if cost else {}
         return dict(cost or {})
@@ -2021,6 +2027,14 @@ class SecureServingEngine(SubmitAPI):
             # MACs entirely and never enter the fused kernel, so they
             # must not count as fused ticks.)
             self.stats["fused_mixed_ticks"] += 1
+        if self.spec.cfg.verify != "none":
+            # Which implementation verified this tick's page read: the
+            # fused kernel, or the jnp reference (wide-block schemes,
+            # or kernels off).
+            if kvp._kernel_read_ok(self.spec):
+                self.stats["fused_read_ticks"] += 1
+            else:
+                self.stats["reference_read_ticks"] += 1
         if kvp._kernel_write_ok(self.spec) and \
                 self.spec.cfg.verify != "none":
             # The tick's dirty-page reseal runs the one-pass fused

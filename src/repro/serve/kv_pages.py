@@ -471,7 +471,14 @@ def _shard_ctr_word(spec: PageSpec) -> jnp.ndarray:
 def _block_counters(spec: PageSpec, leaf: LeafPageSpec, page_ids: jax.Array,
                     vns: jax.Array,
                     ctx: PageKeyCtx | None = None) -> jax.Array:
-    """PA||VN counter words per optBlk: (N * n_blocks, 4) u32.
+    """PA||VN counter words per optBlk: (N * n_blocks, 4) u32 (see
+    :func:`_counter_words`)."""
+    return jnp.stack(_counter_words(spec, leaf, page_ids, vns, ctx), axis=-1)
+
+
+def _counter_words(spec: PageSpec, leaf: LeafPageSpec, page_ids: jax.Array,
+                   vns: jax.Array, ctx: PageKeyCtx | None = None) -> list:
+    """PA||VN counter words per optBlk: four (N * n_blocks,) u32 columns.
 
     With a tenant ctx, word 0 carries the tenant-epoch VN salt and
     word 2 the ``tenant_idx ‖ epoch`` identity, so CTR streams never
@@ -483,10 +490,9 @@ def _block_counters(spec: PageSpec, leaf: LeafPageSpec, page_ids: jax.Array,
     vn_col = jnp.repeat(vns.astype(jnp.uint32), leaf.n_blocks)
     shard_w = _shard_ctr_word(spec)
     if ctx is None:
-        word0 = jnp.full_like(pa, shard_w)
-        return jnp.stack([word0, pa, jnp.zeros_like(pa), vn_col], axis=-1)
+        return [jnp.full_like(pa, shard_w), pa, jnp.zeros_like(pa), vn_col]
     salts, tenant = _tenant_words(ctx, leaf.n_blocks)
-    return jnp.stack([salts ^ shard_w, pa, tenant, vn_col], axis=-1)
+    return [salts ^ shard_w, pa, tenant, vn_col]
 
 
 def _block_binding(spec: PageSpec, leaf: LeafPageSpec, page_ids: jax.Array,
@@ -562,14 +568,8 @@ def _crypt(spec: PageSpec, leaf: LeafPageSpec, buf: jax.Array,
             key, round_keys = keys.key, keys.round_keys
         else:
             key, round_keys, _ = _uniform_keys(ctx)
-        narrow = cfg.block_bytes // SEGMENT_BYTES <= 11
-        if spec.use_kernel and narrow:
-            from repro.kernels.otp_xor.ops import baes_encrypt_kernel
-            out = baes_encrypt_kernel(buf.reshape(-1), round_keys,
-                                      counters, block_bytes=cfg.block_bytes)
-        else:
-            out = baes.baes_encrypt(buf.reshape(-1), round_keys, counters,
-                                    block_bytes=cfg.block_bytes, key=key)
+        out = baes.baes_encrypt(buf.reshape(-1), round_keys, counters,
+                                block_bytes=cfg.block_bytes, key=key)
         return out.reshape(buf.shape)
     # T-AES: one AES invocation per 16B segment, PA advancing per segment.
     segs_per_page = leaf.page_bytes // SEGMENT_BYTES
@@ -626,58 +626,99 @@ def _page_block_macs(spec: PageSpec, leaf: LeafPageSpec, ct: jax.Array,
     return macs.reshape(n, leaf.n_blocks, mac.MAC_BYTES)
 
 
+# Pages per fused dispatch: the crossing runs over the window in chunks
+# of about this many bytes, so its byte <-> word-plane relayouts stay
+# small however many pages a tick touches.
+_FUSED_CHUNK_BYTES = 8 << 20
+
+
 def _fused_crossing(spec: PageSpec, leaf: LeafPageSpec, buf: jax.Array,
                     page_ids: jax.Array, vns: jax.Array, keys,
                     ctx: PageKeyCtx | None, uniform: bool, write: bool):
-    """One kernel-fused crypt + optBlk-MAC pass over page bytes.
+    """Kernel-fused crypt + optBlk-MAC pass over N pages.
 
     Read (``write=False``: decrypt + hash the incoming ciphertext) and
     write (``write=True``: encrypt + hash the fresh ciphertext) build
-    the SAME binding/counters and key selections — only the kernel pair
-    differs, so the two directions cannot drift apart.  ``ctx=None``
-    (engine-wide keys) and uniform ctxs run the single-key kernel; a
-    MIXED ctx (pages resolving to different bank rows) runs the
-    mixed-key kernel, which gathers each page's round-key schedule and
-    NH key row from the bank and stays fused — the tenant words land in
-    the binding/counters either way.
+    the SAME binding/counters and key selections — only the kernel
+    direction differs, so the two cannot drift apart.  ``ctx=None``
+    (engine-wide keys) and uniform ctxs run single-key; a MIXED ctx
+    (pages resolving to different bank rows) gathers each page's
+    round-key schedule and NH key row from the bank and stays fused —
+    the tenant words land in the binding/counters either way.
+
+    Reads take (N, page_bytes) u8 ciphertext and return the plaintext
+    as (N, page values) of the leaf's dtype; writes take the values and
+    return u8 ciphertext — the kernels convert to and from their word
+    planes directly, with no byte-level relayout of the plaintext.  The
+    pages go through in chunks of ``_FUSED_CHUNK_BYTES`` (one
+    ``lax.map`` step each).  Returns ``(out, page MACs (N, MAC_BYTES)
+    u8)`` — each page's optBlk MACs already XOR-aggregated, which is
+    all a layer-verified scheme keeps.
     """
     from repro.kernels.fused_crypt_mac import ops as fused_ops
-    cfg = spec.cfg
-    binding = _block_binding(spec, leaf, page_ids, vns, ctx)
-    counters = _block_counters(spec, leaf, page_ids, vns, ctx)
-    if ctx is not None and not uniform:
-        kernel = (fused_ops.secure_write_kernel_mixed if write
-                  else fused_ops.secure_read_kernel_mixed)
-        rows = jnp.repeat(ctx.key_idx, leaf.n_blocks)
-        out, macs = kernel(
-            buf.reshape(-1), binding, ctx.bank_round_keys, counters,
-            ctx.bank_hash_key, rows, block_bytes=cfg.block_bytes)
+    bb = spec.cfg.block_bytes
+    n = page_ids.shape[0]
+    per = max(1, min(n, _FUSED_CHUNK_BYTES // leaf.page_bytes))
+    chunks = -(-n // per)
+
+    def chunked(x, fill=0):
+        x = jnp.pad(x, ((0, chunks * per - n),) + ((0, 0),) * (x.ndim - 1),
+                    constant_values=fill)
+        return x.reshape((chunks, per) + x.shape[1:])
+
+    per_page = [buf, page_ids, vns.astype(jnp.uint32)]
+    if ctx is not None:
+        per_page += [ctx.key_idx, ctx.owners, ctx.epochs]
+    xs = [chunked(x) for x in per_page]
+    xs[1] = chunked(page_ids, spec.scratch_page)
+    if ctx is None:
+        round_keys, hash_key = keys.round_keys, keys.hash_key
+    elif uniform:
+        _, round_keys, hash_key = _uniform_keys(ctx)
     else:
-        kernel = (fused_ops.secure_write_kernel if write
-                  else fused_ops.secure_read_kernel)
-        if ctx is None:
-            round_keys, hash_key = keys.round_keys, keys.hash_key
-        else:
-            _, round_keys, hash_key = _uniform_keys(ctx)
-        out, macs = kernel(
-            buf.reshape(-1), binding, round_keys, counters, hash_key,
-            block_bytes=cfg.block_bytes)
-    return (out.reshape(buf.shape),
-            macs.reshape(page_ids.shape[0], leaf.n_blocks, mac.MAC_BYTES))
+        round_keys, hash_key = ctx.bank_round_keys, ctx.bank_hash_key
+
+    def one(chunk):
+        data, ids, vn = chunk[:3]
+        cctx = (None if ctx is None else
+                ctx._replace(key_idx=chunk[3], owners=chunk[4],
+                             epochs=chunk[5]))
+        rows = (None if ctx is None or uniform
+                else jnp.repeat(cctx.key_idx, leaf.n_blocks))
+        out, words = fused_ops.secure_crossing(
+            data.reshape(-1), _block_binding(spec, leaf, ids, vn, cctx),
+            jnp.stack(_counter_words(spec, leaf, ids, vn, cctx)),
+            round_keys, hash_key, block_bytes=bb, write=write,
+            out_dtype=out_dtype, row_idx=rows)
+        agg = jax.lax.reduce(words.reshape(2, per, leaf.n_blocks),
+                             jnp.uint32(0), jax.lax.bitwise_xor, (2,))
+        return out.reshape(per, -1), agg
+
+    out_dtype = jnp.uint8 if write else jnp.dtype(leaf.dtype)
+    out, agg = jax.lax.map(one, xs)
+    agg = agg.transpose(1, 0, 2).reshape(2, chunks * per)[:, :n]
+    page_macs = jax.lax.bitcast_convert_type(agg.T, jnp.uint8)
+    return (out.reshape(chunks * per, -1)[:n],
+            page_macs.reshape(n, mac.MAC_BYTES))
 
 
 def _fused_read(spec: PageSpec, leaf: LeafPageSpec, ct: jax.Array,
                 page_ids: jax.Array, vns: jax.Array, keys,
                 ctx: PageKeyCtx | None = None, uniform: bool = False):
-    """Kernel-fused decrypt + optBlk MACs (see :func:`_fused_crossing`)."""
+    """Kernel-fused decrypt + page MACs (see :func:`_fused_crossing`)."""
     return _fused_crossing(spec, leaf, ct, page_ids, vns, keys, ctx,
                            uniform, write=False)
 
 
 def _kernel_read_ok(spec: PageSpec) -> bool:
+    """Narrow-block B-AES + NH with layer-level verification — the
+    envelope the fused kernels (and their page-aggregated MACs) cover."""
     cfg = spec.cfg
     return (spec.use_kernel and cfg.baes and cfg.mac_engine == "nh"
-            and cfg.block_bytes // SEGMENT_BYTES <= 11)
+            and cfg.verify == "layer"
+            and cfg.block_bytes // SEGMENT_BYTES <= 11
+            and all(jnp.dtype(l.dtype).itemsize in (1, 2, 4)
+                    for l in spec.leaves))
 
 
 # The fused write kernel has the same capability envelope as the read
@@ -690,7 +731,7 @@ _kernel_write_ok = _kernel_read_ok
 def _fused_write(spec: PageSpec, leaf: LeafPageSpec, buf: jax.Array,
                  page_ids: jax.Array, vns: jax.Array, keys,
                  ctx: PageKeyCtx | None = None, uniform: bool = False):
-    """Kernel-fused encrypt + optBlk MACs: the dirty page's plaintext
+    """Kernel-fused encrypt + page MACs: the dirty page's plaintext
     is re-encrypted and its fresh ciphertext NH-hashed in ONE Pallas
     visit, instead of an encrypt dispatch followed by a MAC dispatch
     re-reading the ciphertext (see :func:`_fused_crossing`)."""
@@ -703,9 +744,25 @@ def _fused_write(spec: PageSpec, leaf: LeafPageSpec, buf: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def _page_values(leaf: LeafPageSpec, buf: jax.Array) -> jax.Array:
+    """(..., page_bytes) u8 -> (..., page_bytes // itemsize) leaf-dtype
+    values (the same bytes, little-endian)."""
+    dtype = jnp.dtype(leaf.dtype)
+    if dtype.itemsize == 1:
+        return jax.lax.bitcast_convert_type(buf, dtype)
+    grouped = buf.reshape(buf.shape[:-1] + (-1, dtype.itemsize))
+    return jax.lax.bitcast_convert_type(grouped, dtype)
+
+
+def _values_to_bytes(leaf: LeafPageSpec, vals: jax.Array) -> jax.Array:
+    """Inverse of :func:`_page_values`."""
+    as_u8 = jax.lax.bitcast_convert_type(vals, jnp.uint8)
+    return as_u8.reshape(vals.shape[:-1] + (leaf.page_bytes,))
+
+
 def _pages_to_dense(spec: PageSpec, leaf: LeafPageSpec, pt: jax.Array,
                     lengths: jax.Array) -> jax.Array:
-    """(S, P, page_bytes) u8 -> (steps, S, P*page_tokens, *rest), invalid
+    """(S, P, page values) -> (steps, S, P*page_tokens, *rest), invalid
     token positions (>= length) zeroed so masked attention never sees
     decrypt garbage (and schemes stay token-bit-identical).
 
@@ -718,12 +775,11 @@ def _pages_to_dense(spec: PageSpec, leaf: LeafPageSpec, pt: jax.Array,
     s, p = pt.shape[:2]
     ptok = spec.page_tokens
     win_len = p * ptok
-    per_layer = pt.reshape(s, p, leaf.steps, leaf.lp_bytes)
-    payload = per_layer[..., : ptok * leaf.tok_bytes]
     itemsize = jnp.dtype(leaf.dtype).itemsize
     elems = leaf.tok_bytes // itemsize
-    grouped = payload.reshape(s, p, leaf.steps, ptok, elems, itemsize)
-    vals = jax.lax.bitcast_convert_type(grouped, jnp.dtype(leaf.dtype))
+    per_layer = pt.reshape(s, p, leaf.steps, leaf.lp_bytes // itemsize)
+    vals = per_layer[..., : ptok * elems].reshape(s, p, leaf.steps, ptok,
+                                                  elems)
     # (S, P, steps, ptok, elems) -> (steps, S, P*ptok, *rest)
     dense = vals.transpose(2, 0, 1, 3, 4).reshape(
         (leaf.steps, s, win_len) + leaf.rest)
@@ -735,34 +791,26 @@ def _pages_to_dense(spec: PageSpec, leaf: LeafPageSpec, pt: jax.Array,
 
 def _dense_to_pages(spec: PageSpec, leaf: LeafPageSpec,
                     pages: jax.Array) -> jax.Array:
-    """(N, steps, ptok, *rest) token data -> (N, page_bytes) u8."""
+    """(N, steps, ptok, *rest) token data -> (N, page values), each
+    layer's payload zero-padded to the block granularity."""
     n = pages.shape[0]
-    ptok = spec.page_tokens
     itemsize = jnp.dtype(leaf.dtype).itemsize
-    if jnp.dtype(leaf.dtype) == jnp.uint8:
-        flat = pages.reshape(n, leaf.steps, ptok * leaf.tok_bytes)
-    else:
-        as_u8 = jax.lax.bitcast_convert_type(pages, jnp.uint8)
-        flat = as_u8.reshape(n, leaf.steps, ptok * leaf.tok_bytes)
-    pad = leaf.lp_bytes - ptok * leaf.tok_bytes
+    flat = pages.reshape(n, leaf.steps, -1)
+    pad = leaf.lp_bytes // itemsize - flat.shape[-1]
     if pad:
         flat = jnp.pad(flat, ((0, 0), (0, 0), (0, pad)))
-    return flat.reshape(n, leaf.page_bytes)
+    return flat.reshape(n, leaf.page_bytes // itemsize)
 
 
-def _bytes_to_tokens(spec: PageSpec, leaf: LeafPageSpec,
-                     buf: jax.Array) -> jax.Array:
-    """(N, page_bytes) u8 -> (N, steps, ptok, *rest) token data
+def _values_to_tokens(spec: PageSpec, leaf: LeafPageSpec,
+                      vals: jax.Array) -> jax.Array:
+    """(N, page values) -> (N, steps, ptok, *rest) token data
     (inverse of :func:`_dense_to_pages`, padding stripped)."""
-    n = buf.shape[0]
+    n = vals.shape[0]
     ptok = spec.page_tokens
-    per_layer = buf.reshape(n, leaf.steps, leaf.lp_bytes)
-    payload = per_layer[..., : ptok * leaf.tok_bytes]
-    itemsize = jnp.dtype(leaf.dtype).itemsize
-    elems = leaf.tok_bytes // itemsize
-    grouped = payload.reshape(n, leaf.steps, ptok, elems, itemsize)
-    vals = jax.lax.bitcast_convert_type(grouped, jnp.dtype(leaf.dtype))
-    return vals.reshape((n, leaf.steps, ptok) + leaf.rest)
+    elems = leaf.tok_bytes // jnp.dtype(leaf.dtype).itemsize
+    per_layer = vals.reshape(n, leaf.steps, -1)[..., : ptok * elems]
+    return per_layer.reshape((n, leaf.steps, ptok) + leaf.rest)
 
 
 # ---------------------------------------------------------------------------
@@ -859,27 +907,29 @@ class PageIO:
             ct = pool.cts[li][flat_ids].reshape(s, p, leaf.page_bytes)
             need_macs = cfg.verify != "none"
             if need_macs and _kernel_read_ok(spec):
-                pt, macs = _fused_read(spec, leaf,
-                                       ct.reshape(-1, leaf.page_bytes),
-                                       flat_ids, vns, keys, ctx, uniform)
-                pt = pt.reshape(s, p, leaf.page_bytes)
-                macs = macs.reshape(s, p, leaf.n_blocks, mac.MAC_BYTES)
-            else:
-                pt = _crypt(spec, leaf, ct.reshape(-1, leaf.page_bytes),
-                            flat_ids, vns, keys, ctx,
-                            uniform).reshape(s, p, leaf.page_bytes)
-                macs = None
-                if need_macs:
-                    macs = _page_block_macs(
-                        spec, leaf, ct.reshape(-1, leaf.page_bytes), flat_ids,
-                        vns, keys, ctx, uniform).reshape(s, p, leaf.n_blocks,
-                                                         mac.MAC_BYTES)
+                pt, page_macs = _fused_read(spec, leaf,
+                                            ct.reshape(-1, leaf.page_bytes),
+                                            flat_ids, vns, keys, ctx, uniform)
+                dense.append(_pages_to_dense(
+                    spec, leaf, pt.reshape(s, p, -1), lengths))
+                agg = agg ^ page_macs.reshape(s, p, mac.MAC_BYTES)
+                continue
+            pt = _crypt(spec, leaf, ct.reshape(-1, leaf.page_bytes),
+                        flat_ids, vns, keys, ctx,
+                        uniform).reshape(s, p, leaf.page_bytes)
+            macs = None
+            if need_macs:
+                macs = _page_block_macs(
+                    spec, leaf, ct.reshape(-1, leaf.page_bytes), flat_ids,
+                    vns, keys, ctx, uniform).reshape(s, p, leaf.n_blocks,
+                                                     mac.MAC_BYTES)
             if cfg.verify == "block":
                 stored = pool.block_macs[li][flat_ids].reshape(macs.shape)
                 ok = ok & jnp.all((macs == stored) | ~touched[..., None, None])
             elif cfg.verify == "layer":
                 agg = agg ^ mac.xor_aggregate(macs, axis=2)
-            dense.append(_pages_to_dense(spec, leaf, pt, lengths))
+            dense.append(_pages_to_dense(spec, leaf, _page_values(leaf, pt),
+                                         lengths))
         if cfg.verify == "layer":
             stored = pool.page_macs[flat_ids].reshape(s, p, mac.MAC_BYTES)
             ok = ok & jnp.all((agg == stored) | ~touched[..., None])
@@ -915,20 +965,22 @@ class PageIO:
         new_cts = []
         new_block_macs = list(pool.block_macs)
         for li, leaf in enumerate(spec.leaves):
-            buf = _dense_to_pages(spec, leaf, leaf_pages[li])
+            vals = _dense_to_pages(spec, leaf, leaf_pages[li])
             if cfg.verify != "none" and _kernel_write_ok(spec):
                 # One fused Pallas pass: encrypt + NH of the fresh
                 # ciphertext — the write-side twin of the fused read,
                 # for uniform AND mixed-row key selections.
-                ct, macs = _fused_write(spec, leaf, buf, page_ids, vns, keys,
+                ct, page_macs = _fused_write(spec, leaf, vals, page_ids, vns,
+                                             keys, ctx, uniform)
+                new_cts.append(pool.cts[li].at[page_ids].set(ct))
+                agg = agg ^ page_macs
+                continue
+            ct = _crypt(spec, leaf, _values_to_bytes(leaf, vals), page_ids,
+                        vns, keys, ctx, uniform)
+            macs = None
+            if cfg.verify != "none":
+                macs = _page_block_macs(spec, leaf, ct, page_ids, vns, keys,
                                         ctx, uniform)
-            else:
-                ct = _crypt(spec, leaf, buf, page_ids, vns, keys, ctx,
-                            uniform)
-                macs = None
-                if cfg.verify != "none":
-                    macs = _page_block_macs(spec, leaf, ct, page_ids, vns,
-                                            keys, ctx, uniform)
             new_cts.append(pool.cts[li].at[page_ids].set(ct))
             if cfg.verify != "none":
                 if cfg.verify == "block":
@@ -1023,21 +1075,22 @@ class PageIO:
             ct = pool.cts[li][page_ids]
             need_macs = cfg.verify != "none"
             if need_macs and _kernel_read_ok(spec):
-                pt, macs = _fused_read(spec, leaf, ct, page_ids, vns, keys,
-                                       ctx, uniform)
-            else:
-                pt = _crypt(spec, leaf, ct, page_ids, vns, keys, ctx,
-                            uniform)
-                macs = None
-                if need_macs:
-                    macs = _page_block_macs(spec, leaf, ct, page_ids, vns,
+                pt, page_macs = _fused_read(spec, leaf, ct, page_ids, vns,
                                             keys, ctx, uniform)
+                agg = agg ^ page_macs
+                out.append(_values_to_tokens(spec, leaf, pt))
+                continue
+            pt = _crypt(spec, leaf, ct, page_ids, vns, keys, ctx, uniform)
+            macs = None
+            if need_macs:
+                macs = _page_block_macs(spec, leaf, ct, page_ids, vns, keys,
+                                        ctx, uniform)
             if cfg.verify == "block":
                 stored = pool.block_macs[li][page_ids]
                 ok = ok & jnp.all((macs == stored) | ~real[:, None, None])
             elif cfg.verify == "layer":
                 agg = agg ^ mac.xor_aggregate(macs, axis=1)
-            out.append(_bytes_to_tokens(spec, leaf, pt))
+            out.append(_values_to_tokens(spec, leaf, _page_values(leaf, pt)))
         if cfg.verify == "layer":
             stored = pool.page_macs[page_ids]
             ok = ok & jnp.all((agg == stored) | ~real[:, None])

@@ -70,11 +70,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from repro.launch.cells import build_cell
-try:  # axis_types only exists on newer jax; Auto is the default anyway
-    mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-except AttributeError:
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cell = build_cell("smollm-135m", "{shape}", mesh, smoke=True)
 compiled = cell.lower(mesh).compile()
 assert compiled.cost_analysis() is not None

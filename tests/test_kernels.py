@@ -6,12 +6,13 @@ import numpy as np
 import pytest
 from _hyp import given, settings, st
 
-from repro.core import aes, baes, mac
+from repro.core import baes, mac
 from repro.core.secure_memory import SecureKeys
-from repro.kernels.aes_ctr import ops as aes_ops
+from repro.kernels.aes_ctr import kernel as aes_k
 from repro.kernels.aes_ctr.ref import (aes_ctr_keystream_lanes_ref,
                                        aes_ctr_keystream_ref)
-from repro.kernels.fused_crypt_mac.kernel import (fused_crypt_mac_mixed,
+from repro.kernels.fused_crypt_mac.kernel import (fused_crypt_mac,
+                                                  fused_crypt_mac_mixed,
                                                   fused_crypt_mac_write,
                                                   fused_crypt_mac_write_mixed)
 from repro.kernels.fused_crypt_mac.ops import (secure_read_kernel,
@@ -20,11 +21,8 @@ from repro.kernels.fused_crypt_mac.ops import (secure_read_kernel,
                                                secure_write_kernel_mixed)
 from repro.kernels.fused_crypt_mac.ref import (fused_crypt_mac_mixed_ref,
                                                fused_crypt_mac_write_mixed_ref,
-                                               fused_crypt_mac_write_ref)
-from repro.kernels.otp_xor import ops as ox_ops
-from repro.kernels.otp_xor.ref import otp_xor_ref
-from repro.kernels.xormac import ops as xm_ops
-from repro.kernels.xormac.ref import nh_hash_ref
+                                               fused_crypt_mac_write_ref,
+                                               nh_hash_ref, otp_xor_ref)
 
 
 @pytest.fixture(scope="module")
@@ -32,42 +30,65 @@ def kkeys():
     return SecureKeys.derive(77)
 
 
+def _aes_lanes_ref_multi(cw, rk_per):
+    return jax.vmap(lambda c, rk: aes_ctr_keystream_lanes_ref(c[None], rk)[0])(
+        cw, rk_per)
+
+
 class TestAESCTRKernel:
     @pytest.mark.parametrize("n", [1, 7, 256, 1000])
-    @pytest.mark.parametrize("subbytes", ["take", "onehot"])
-    def test_vs_oracle(self, kkeys, n, subbytes):
+    @pytest.mark.parametrize("keying", ["single", "per_block"])
+    def test_vs_oracle(self, kkeys, n, keying):
         rng = np.random.default_rng(n)
         cw = jnp.asarray(rng.integers(0, 2**32, (n, 4), dtype=np.uint32))
-        got = aes_ops.keystream_lanes(cw, kkeys.round_keys, subbytes=subbytes)
-        want = aes_ctr_keystream_lanes_ref(cw, kkeys.round_keys)
+        if keying == "single":
+            got = aes_k.aes_ctr_keystream(cw, kkeys.round_keys)
+            want = aes_ctr_keystream_lanes_ref(cw, kkeys.round_keys)
+        else:
+            rk_per = jnp.asarray(rng.integers(0, 256, (n, 11, 16),
+                                              dtype=np.uint8))
+            got = aes_k.aes_ctr_keystream_multi(cw, rk_per)
+            want = _aes_lanes_ref_multi(cw, rk_per)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_bytes_layout(self, kkeys):
         cw = jnp.asarray([[0, 5, 0, 9]], dtype=jnp.uint32)
-        got = aes_ops.keystream_bytes(cw, kkeys.round_keys)
+        lanes = aes_k.aes_ctr_keystream(cw, kkeys.round_keys)
+        got = jax.lax.bitcast_convert_type(lanes, jnp.uint8).reshape(1, 16)
         want = aes_ctr_keystream_ref(cw, kkeys.round_keys)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
-    @pytest.mark.parametrize("tile_n", [8, 64, 512])
-    def test_tile_sweep(self, kkeys, tile_n):
+    @pytest.mark.parametrize("tile_rows", [8, 64, 512])
+    def test_tile_sweep(self, kkeys, tile_rows):
+        """Multi-step grids (and padded tails) agree with the oracle."""
         rng = np.random.default_rng(1)
-        cw = jnp.asarray(rng.integers(0, 2**32, (100, 4), dtype=np.uint32))
-        got = aes_ops.keystream_lanes(cw, kkeys.round_keys)
-        from repro.kernels.aes_ctr.kernel import aes_ctr_keystream
-        got_t = aes_ctr_keystream(cw, kkeys.round_keys, tile_n=tile_n)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(got_t))
+        cw = jnp.asarray(rng.integers(0, 2**32, (20000, 4), dtype=np.uint32))
+        got_t = aes_k.aes_ctr_keystream(cw, kkeys.round_keys,
+                                        tile_rows=tile_rows)
+        want = aes_ctr_keystream_lanes_ref(cw, kkeys.round_keys)
+        np.testing.assert_array_equal(np.asarray(got_t), np.asarray(want))
 
 
-class TestOtpXorKernel:
+def _rand_u32(rng, shape):
+    return jnp.asarray(rng.integers(0, 2**32, shape, dtype=np.uint32))
+
+
+class TestFusedCryptHalf:
+    """The crypt engine half of the fused kernels: the diversified pad
+    XOR, against its oracle and against the core B-AES cipher."""
+
     @pytest.mark.parametrize("n,s", [(1, 2), (13, 4), (300, 8), (64, 32)])
     def test_vs_oracle(self, n, s):
         rng = np.random.default_rng(n * s)
-        data = jnp.asarray(rng.integers(0, 2**32, (n, s * 4), dtype=np.uint32))
-        base = jnp.asarray(rng.integers(0, 2**32, (n, 4), dtype=np.uint32))
-        div = jnp.asarray(rng.integers(0, 2**32, (s, 4), dtype=np.uint32))
-        got = ox_ops.otp_xor(data, base, div)
+        data = _rand_u32(rng, (n, s * 4))
+        base = _rand_u32(rng, (n, 4))
+        div = _rand_u32(rng, (s, 4))
+        bind = _rand_u32(rng, (n, 8))
+        key = _rand_u32(rng, (s * 4 + 8,))
         want = otp_xor_ref(data, base, div)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        for kernel in (fused_crypt_mac, fused_crypt_mac_write):
+            got, _ = kernel(data, base, div, bind, key)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     @pytest.mark.parametrize("block_bytes", [32, 64, 128])
     def test_full_baes_path_vs_core(self, kkeys, block_bytes):
@@ -78,42 +99,52 @@ class TestOtpXorKernel:
             [np.zeros(n, np.uint32),
              np.arange(n, dtype=np.uint32) * (block_bytes // 16),
              np.zeros(n, np.uint32), np.full(n, 3, np.uint32)], -1))
-        got = ox_ops.baes_encrypt_kernel(pt, kkeys.round_keys, cw,
-                                         block_bytes=block_bytes)
+        bind = mac.Binding.make(np.arange(n) * 4, 3, 0, 0, np.arange(n))
+        got, _ = secure_write_kernel(pt, bind, kkeys.round_keys, cw,
+                                     kkeys.hash_key, block_bytes=block_bytes)
         want = baes.baes_encrypt(pt, kkeys.round_keys, cw,
                                  block_bytes=block_bytes, key=kkeys.key)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-class TestXorMacKernel:
-    @pytest.mark.parametrize("n,lanes", [(1, 8), (50, 24), (200, 136)])
-    def test_nh_vs_oracle(self, kkeys, n, lanes):
+class TestFusedHashHalf:
+    """The integ engine half of the fused kernels: NH over
+    ciphertext ‖ binding, and the finalized optBlk/layer MACs."""
+
+    @pytest.mark.parametrize("n,s", [(1, 2), (50, 4), (200, 32)])
+    def test_nh_vs_oracle(self, kkeys, n, s):
         rng = np.random.default_rng(n)
-        payload = jnp.asarray(rng.integers(0, 2**32, (n, lanes),
-                                           dtype=np.uint32))
-        key = kkeys.hash_key[:lanes]
-        got = xm_ops.nh_hash_kernel_call(payload, key)
-        want = nh_hash_ref(payload, key)
+        ct = _rand_u32(rng, (n, s * 4))
+        bind = _rand_u32(rng, (n, 8))
+        key = kkeys.hash_key[: s * 4 + 8]
+        _, got = fused_crypt_mac(ct, _rand_u32(rng, (n, 4)),
+                                 _rand_u32(rng, (s, 4)), bind, key)
+        want = nh_hash_ref(jnp.concatenate([ct, bind], axis=-1), key)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
+    def _blocks(self, seed, n):
+        rng = np.random.default_rng(seed)
+        blocks = jnp.asarray(rng.integers(0, 256, (n, 64), dtype=np.uint8))
+        cw = _rand_u32(rng, (n, 4))
+        return blocks, cw
+
     def test_block_macs_bitexact_vs_core(self, kkeys):
-        rng = np.random.default_rng(2)
-        blocks = jnp.asarray(rng.integers(0, 256, (33, 64), dtype=np.uint8))
+        blocks, cw = self._blocks(2, 33)
         bind = mac.Binding.make(np.arange(33) * 4, 7, 2, 1, np.arange(33))
-        got = xm_ops.block_macs_kernel(blocks, bind,
-                                       hash_key_u32=kkeys.hash_key,
-                                       round_keys=kkeys.round_keys)
+        _, got = secure_read_kernel(blocks.reshape(-1), bind,
+                                    kkeys.round_keys, cw, kkeys.hash_key,
+                                    block_bytes=64)
         want = mac.block_macs(blocks, bind, hash_key_u32=kkeys.hash_key,
                               round_keys=kkeys.round_keys, engine="nh")
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_layer_mac_bitexact(self, kkeys):
-        rng = np.random.default_rng(3)
-        blocks = jnp.asarray(rng.integers(0, 256, (16, 64), dtype=np.uint8))
+        blocks, cw = self._blocks(3, 16)
         bind = mac.Binding.make(np.arange(16) * 4, 9, 0, 0, np.arange(16))
-        got = xm_ops.layer_mac_kernel(blocks, bind,
-                                      hash_key_u32=kkeys.hash_key,
-                                      round_keys=kkeys.round_keys)
+        _, macs = secure_read_kernel(blocks.reshape(-1), bind,
+                                     kkeys.round_keys, cw, kkeys.hash_key,
+                                     block_bytes=64)
+        got = mac.xor_aggregate(macs)
         want = mac.layer_mac(blocks, bind, hash_key_u32=kkeys.hash_key,
                              round_keys=kkeys.round_keys)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -243,8 +274,8 @@ class TestFusedCryptMacMixed:
         rng = np.random.default_rng(2)
         cw = jnp.asarray(rng.integers(0, 2**32, (50, 4), dtype=np.uint32))
         rk_per = jnp.broadcast_to(kkeys.round_keys[None], (50, 11, 16))
-        got = aes_ops.keystream_lanes_multi(cw, rk_per)
-        want = aes_ops.keystream_lanes(cw, kkeys.round_keys)
+        got = aes_k.aes_ctr_keystream_multi(cw, rk_per)
+        want = aes_k.aes_ctr_keystream(cw, kkeys.round_keys)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 

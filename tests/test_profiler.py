@@ -67,9 +67,26 @@ class TestAttributeHlo:
     HLO = """\
 HloModule test
 
+FileNames
+1 "/x/repro/core/aes.py"
+2 "/x/repro/models/layers.py"
+
+FunctionNames
+1 "f"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=5 end_line=5 column=1 end_column=2}
+2 {file_name_id=2 function_name_id=1 line=9 end_line=9 column=1 end_column=2}
+3 {file_name_id=1 function_name_id=1 line=7 end_line=7 column=1 end_column=2}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+2 {file_location_id=2 parent_frame_id=1}
+3 {file_location_id=3 parent_frame_id=1}
+
 %fused_computation (param_0.1: f32[8]) -> f32[8] {
   %param_0.1 = f32[8]{0} parameter(0)
-  ROOT %m = f32[8]{0} multiply(f32[8]{0} %param_0.1, f32[8]{0} %param_0.1), metadata={op_name="mul" source_file="/x/repro/core/aes.py" source_line=5}
+  ROOT %m = f32[8]{0} multiply(f32[8]{0} %param_0.1, f32[8]{0} %param_0.1), metadata={op_name="mul" stack_frame_id=1}
 }
 
 %helper (a.1: f32[8]) -> f32[8] {
@@ -80,9 +97,9 @@ HloModule test
 ENTRY %main (p0: f32[8], p1: f32[4,4]) -> f32[8] {
   %p0 = f32[8]{0} parameter(0)
   %p1 = f32[4,4]{1,0} parameter(1)
-  %d = f32[4,4]{1,0} dot(f32[4,4]{1,0} %p1, f32[4,4]{1,0} %p1), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="mm" source_file="/x/repro/models/layers.py" source_line=9}
+  %d = f32[4,4]{1,0} dot(f32[4,4]{1,0} %p1, f32[4,4]{1,0} %p1), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="mm" stack_frame_id=2}
   %f = f32[8]{0} fusion(f32[8]{0} %p0), kind=kLoop, calls=%fused_computation
-  ROOT %c = f32[8]{0} call(f32[8]{0} %f), to_apply=%helper, metadata={op_name="bc" source_file="/x/repro/core/aes.py" source_line=7}
+  ROOT %c = f32[8]{0} call(f32[8]{0} %f), to_apply=%helper, metadata={op_name="bc" stack_frame_id=3}
 }
 """
 
