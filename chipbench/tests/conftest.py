@@ -1,0 +1,11 @@
+"""The benchmark's own tests run on the CPU: the checkout root (for the
+``chipbench`` package) and ``src`` (for the program) go on the path."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (ROOT, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
