@@ -122,6 +122,7 @@ def aes_planes(ctr: jax.Array, round_keys: jax.Array, *,
         out_specs=plane_spec(4, tile),
         out_shape=jax.ShapeDtypeStruct((4, rows, LANES), jnp.uint32),
         interpret=interpret,
+        name="aes_ctr_keystream" + ("_mixed" if per_block else ""),
     )(ctr.astype(jnp.uint32), rk, jnp.asarray(_SBOX_HALVES_NP))
 
 

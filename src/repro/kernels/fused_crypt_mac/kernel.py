@@ -131,6 +131,8 @@ def fused_planes(data: jax.Array, base: jax.Array, div: jax.Array,
             jax.ShapeDtypeStruct((2, rows, LANES), jnp.uint32),
         ],
         interpret=interpret,
+        name=("fused_crypt_mac_" + ("write" if write else "read")
+              + ("_mixed" if div.ndim == 3 else "")),
     )(data, base, div.astype(jnp.uint32), bind, key.astype(jnp.uint32))
 
 

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Any, NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.models import attention as attn_mod
@@ -149,14 +150,18 @@ def block_prefill(cfg, kind: LayerKind, params, x, positions, aux, max_len):
 
 
 def block_decode(cfg, kind: LayerKind, params, x, cache, aux):
-    h = rms_norm(x, params["norm_mixer"])
-    if kind.mixer == "attn":
-        out, cache = attn_mod.decode_attention(params["attn"], h, cache)
-    elif kind.mixer == "mla":
-        out, cache = mla_mod.mla_decode(cfg.mla, params["mla"], h, cache)
-    else:
-        out, cache = mamba_mod.mamba2_decode(cfg.mamba, params["mamba"], h,
-                                             cache)
-    x = x + out
-    x, aux = _apply_ffn(cfg, kind, params, x, aux)
+    # Named scopes mark the block's layers in the compiled HLO's op_name.
+    with jax.named_scope("model.attention" if kind.mixer != "mamba"
+                         else "model.mamba"):
+        h = rms_norm(x, params["norm_mixer"])
+        if kind.mixer == "attn":
+            out, cache = attn_mod.decode_attention(params["attn"], h, cache)
+        elif kind.mixer == "mla":
+            out, cache = mla_mod.mla_decode(cfg.mla, params["mla"], h, cache)
+        else:
+            out, cache = mamba_mod.mamba2_decode(cfg.mamba, params["mamba"],
+                                                 h, cache)
+        x = x + out
+    with jax.named_scope("model.ffn"):
+        x, aux = _apply_ffn(cfg, kind, params, x, aux)
     return x, cache, aux

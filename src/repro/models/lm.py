@@ -290,9 +290,10 @@ def lm_decode(cfg: LMConfig, params, tokens, caches) -> tuple:
 
         x, new_seg = jax.lax.scan(body, x, (seg_params, tuple(seg_cache)))
         new_caches.append(list(new_seg))
-    x = rms_norm(x, params["final_norm"])
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bld,vd->blv", x, params["embed"])
-    else:
-        logits = jnp.einsum("bld,dv->blv", x, params["lm_head"])
+    with jax.named_scope("model.head"):
+        x = rms_norm(x, params["final_norm"])
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bld,vd->blv", x, params["embed"])
+        else:
+            logits = jnp.einsum("bld,dv->blv", x, params["lm_head"])
     return constrain(logits, "batch", None, "vocab"), new_caches

@@ -42,6 +42,7 @@ ENGINE_COUNTERS = {
     "deferred_checks": "off-critical-path deferred pool-MAC checks",
     "rotations": "tenant key rotations observed by this engine",
     "prefill_compiles": "distinct prefill shapes compiled",
+    "prefills": "batch-1 prefill dispatches (a prefix hit runs none)",
     "reseals": "eager pre-rotation reseal dispatches",
     "uniform_fast_ticks": "single-bank-row ticks on the flat crypto route",
     "fused_mixed_ticks": "mixed-row ticks kept on the fused READ kernel",

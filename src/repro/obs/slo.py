@@ -26,8 +26,8 @@ Every breach is emitted twice: as a registry counter (names declared
 in :data:`repro.obs.metrics.ENGINE_COUNTERS`) *and* as a hash-chained
 audit event (``slo_breach`` with a ``kind`` field) when the engine has
 an audit log.  Attachment is explicit (``monitor.attach(engine)``)
-and wraps the tick phases per instance exactly like the span tracer
-does — an engine without a monitor executes zero additional host code.
+and wraps the tick phases per instance — an engine without a monitor
+executes zero additional host code.
 """
 
 from __future__ import annotations

@@ -47,7 +47,6 @@ replay rejection — behaves identically.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Optional
 
@@ -166,30 +165,18 @@ class ClusterEngine(SubmitAPI):
         # with shard 0's phase spans.  Each shard engine traces under
         # pid=shard_id (they build their own tracers above).
         self.tracer = None
+        self._span_hists: dict = {}
         if trace:
             self.tracer = (trace if isinstance(trace, trace_mod.SpanTracer)
                            else trace_mod.SpanTracer(pid=shards))
-            self._instrument_step()
+            self._span_hists["cluster_tick"] = self.metrics.histogram(
+                "cluster_tick_seconds",
+                metrics_mod.CLUSTER_HISTOGRAMS["cluster_tick_seconds"])
 
     # -- observability -------------------------------------------------------
 
-    def _instrument_step(self) -> None:
-        """Wrap :meth:`step` with a span + wall-clock histogram."""
-        hist = self.metrics.histogram(
-            "cluster_tick_seconds",
-            metrics_mod.CLUSTER_HISTOGRAMS["cluster_tick_seconds"])
-        tracer, inner = self.tracer, self.step
-
-        def wrapper(*a, **kw):
-            t0 = time.perf_counter_ns()
-            try:
-                return inner(*a, **kw)
-            finally:
-                t1 = time.perf_counter_ns()
-                tracer.add("cluster_tick", t0, t1, {"tick": self.tick})
-                hist.observe((t1 - t0) / 1e9)
-
-        self.step = wrapper
+    # ``with self._span(name):`` around one piece of the cluster's work.
+    _span = trace_mod.owner_span
 
     @property
     def stats(self):
@@ -353,32 +340,34 @@ class ClusterEngine(SubmitAPI):
         the other shards' tick proceeds untouched; raises that carry
         page context are contained inside that shard (quarantine +
         recompute) and never escalate to failover."""
-        self.tick += 1
-        if (self.registry is not None and self.rotate_every
-                and self.tick % self.rotate_every == 0
-                and self.registry.n_tenants):
-            idx = self._rotate_rr % self.registry.n_tenants
-            self._rotate_rr += 1
-            self.rotate(self.registry.by_index(idx).tenant_id)
-        finished: list = []
-        if self.ft is None:
-            actives = [e._tick_begin(finished) for e in self.engines]
-            pendings = [e._decode_dispatch(a) if a else None
-                        for e, a in zip(self.engines, actives)]
-            for engine, active, pending in zip(self.engines, actives,
-                                               pendings):
-                if pending is not None:
-                    engine._decode_collect(active, pending, finished)
-            for engine in self.engines:
-                engine._tick_end()
-        else:
-            self._step_ft(finished)
-        if self.migrate and self._n_live() > 1:
-            self._maybe_migrate()
-        self._requeue_orphans()
-        if self.defer_interval and self.tick % self.defer_interval == 0:
-            self._root_check()
-        return finished
+        with self._span("cluster_tick", step_num=self.tick + 1):
+            self.tick += 1
+            if (self.registry is not None and self.rotate_every
+                    and self.tick % self.rotate_every == 0
+                    and self.registry.n_tenants):
+                idx = self._rotate_rr % self.registry.n_tenants
+                self._rotate_rr += 1
+                self.rotate(self.registry.by_index(idx).tenant_id)
+            finished: list = []
+            if self.ft is None:
+                actives = [e._tick_begin(finished) for e in self.engines]
+                pendings = [e._decode_dispatch(a) if a else None
+                            for e, a in zip(self.engines, actives)]
+                for engine, active, pending in zip(self.engines, actives,
+                                                   pendings):
+                    if pending is not None:
+                        engine._decode_collect(active, pending, finished)
+                for engine in self.engines:
+                    engine._tick_end()
+            else:
+                self._step_ft(finished)
+            if self.migrate and self._n_live() > 1:
+                self._maybe_migrate()
+            self._requeue_orphans()
+            if self.defer_interval and \
+                    self.tick % self.defer_interval == 0:
+                self._root_check()
+            return finished
 
     def _step_ft(self, finished: list) -> None:
         """The guarded tick phases: dispatch-all-before-collect-any is

@@ -137,6 +137,7 @@ class Request:
     done_tick: Optional[int] = None
     share_prefix: bool = True       # may use / populate the prefix cache
     submit_time: float = 0.0        # perf_counter at submit (ttft_seconds)
+    queued_ns: int = 0              # perf_counter_ns when last queued
 
     @property
     def done(self) -> bool:
@@ -509,9 +510,10 @@ class SecureServingEngine(SubmitAPI):
         lazy callbacks sampled only at :meth:`snapshot` time.  The span
         tracer and the wall-clock phase histograms only exist when
         ``trace`` was passed (``True`` or a
-        :class:`~repro.obs.trace.SpanTracer`): the tick phases are then
-        wrapped per-instance, so a default engine pays zero timing
-        calls on its hot path.  ``audit`` (``True`` or a shared
+        :class:`~repro.obs.trace.SpanTracer`): :meth:`_span` then times
+        the engine's layer boundaries, and otherwise returns a shared
+        no-op, so a default engine pays no timing call on its hot path.
+        ``audit`` (``True`` or a shared
         :class:`~repro.obs.audit.AuditLog`) enables the hash-chained
         security event log.
         """
@@ -583,10 +585,19 @@ class SecureServingEngine(SubmitAPI):
         else:
             self.audit = None
         self.tracer = None
+        self._span_hists: dict = {}
         if trace:
             self.tracer = (trace if isinstance(trace, trace_mod.SpanTracer)
                            else trace_mod.SpanTracer(pid=self.shard_id))
-            self._instrument_phases()
+            h = metrics_mod.ENGINE_HISTOGRAMS
+            for span, key in (("tick", "tick_seconds"),
+                              ("tick_begin", "phase_tick_begin_seconds"),
+                              ("decode_dispatch",
+                               "phase_decode_dispatch_seconds"),
+                              ("decode_collect",
+                               "phase_decode_collect_seconds"),
+                              ("tick_end", "phase_tick_end_seconds")):
+                self._span_hists[span] = self.metrics.histogram(key, h[key])
         # kv_pages-level integrity verdict hook: every host-synced MAC
         # gate verdict (decode read, reseal, CoW, cache insert/share,
         # migration, deferred checks) lands in the counters no matter
@@ -603,37 +614,8 @@ class SecureServingEngine(SubmitAPI):
         if req.submit_time:
             self._ttft_seconds.observe(time.perf_counter() - req.submit_time)
 
-    def _instrument_phases(self) -> None:
-        """Per-instance wrap of the tick phases with spans + histograms.
-
-        Instance attributes shadow the class methods, so both
-        ``step()`` and a cluster driving the phases directly hit the
-        instrumented versions — and an engine without a tracer never
-        executes a single timing call.
-        """
-        h = metrics_mod.ENGINE_HISTOGRAMS
-        tracer = self.tracer
-
-        def timed(span_name, fn, hist):
-            def wrapper(*a, **kw):
-                t0 = time.perf_counter_ns()
-                try:
-                    return fn(*a, **kw)
-                finally:
-                    t1 = time.perf_counter_ns()
-                    tracer.add(span_name, t0, t1, {"tick": self.tick})
-                    hist.observe((t1 - t0) / 1e9)
-            return wrapper
-
-        for name in ("_tick_begin", "_decode_dispatch", "_decode_collect",
-                     "_tick_end"):
-            key = f"phase{name}_seconds"
-            hist = self.metrics.histogram(key, h[key])
-            setattr(self, name, timed(name.lstrip("_"), getattr(self, name),
-                                      hist))
-        tick_hist = self.metrics.histogram("tick_seconds",
-                                           h["tick_seconds"])
-        self.step = timed("tick", self.step, tick_hist)
+    # ``with self._span(name):`` around one piece of the engine's work.
+    _span = trace_mod.owner_span
 
     @property
     def stats(self):
@@ -708,16 +690,22 @@ class SecureServingEngine(SubmitAPI):
 
         def core(params, pool, onchip, page_table, lengths, active, tokens,
                  epoch, read_ctx, write_ctx):
-            dense, ok = io.read(pool, page_table, lengths, read_ctx, uniform)
+            # Named scopes mark the program's layers in the compiled HLO's
+            # op_name metadata (the profiler's per-op view).
+            with jax.named_scope("pageio.read"):
+                dense, ok = io.read(pool, page_table, lengths, read_ctx,
+                                    uniform)
             caches = self._merge_cache_leaves(dense, onchip, lengths)
             logits, new_caches = lm_mod.lm_decode(cfg, params, tokens, caches)
-            tok = greedy_sample(logits)                    # (S, 1)
+            with jax.named_scope("sample"):
+                tok = greedy_sample(logits)                # (S, 1)
             new_leaves = jax.tree_util.tree_leaves(new_caches)
             vn = vn_mod.kv_page_vn(epoch)
-            new_pool = io.write_dirty(
-                pool, page_table,
-                [new_leaves[i] for i in self.paged_idx], lengths, active, vn,
-                write_ctx, uniform)
+            with jax.named_scope("pageio.write"):
+                new_pool = io.write_dirty(
+                    pool, page_table,
+                    [new_leaves[i] for i in self.paged_idx], lengths, active,
+                    vn, write_ctx, uniform)
             new_onchip = []
             for j, idx in enumerate(self.onchip_idx):
                 leaf = new_leaves[idx]
@@ -854,9 +842,10 @@ class SecureServingEngine(SubmitAPI):
                              "tenant registry")
         rid = self._next_rid
         self._next_rid += 1
+        now = time.perf_counter_ns()
         req = Request(rid, prompt, max_new_tokens, submit_tick=self.tick,
                       share_prefix=bool(request.share_prefix),
-                      submit_time=time.perf_counter())
+                      submit_time=now / 1e9, queued_ns=now)
         self.requests[rid] = req
         if tenant is not None:
             req.tenant_idx = tenant.index
@@ -1140,64 +1129,69 @@ class SecureServingEngine(SubmitAPI):
         of many shard engines — dispatching every shard's decode before
         blocking on any of them.
         """
-        finished: list = []
-        if self.ft is None:
-            active_idx = self._tick_begin(finished)
-            if active_idx:
-                pending = self._decode_dispatch(active_idx)
-                self._decode_collect(active_idx, pending, finished)
-            self._tick_end()
+        with self._span("tick", step_num=self.tick + 1):
+            finished: list = []
+            if self.ft is None:
+                active_idx = self._tick_begin(finished)
+                if active_idx:
+                    pending = self._decode_dispatch(active_idx)
+                    self._decode_collect(active_idx, pending, finished)
+                self._tick_end()
+                return finished
+            # Fault-contained tick: an IntegrityError raised by any phase
+            # (admission reseal/CoW/cache-insert, stale-epoch page-table
+            # checks, the decode MAC gate, the deferred pool check) is
+            # localized and quarantined instead of escaping.  Skipping the
+            # remainder of a phase for one tick is token-invariant: no
+            # slot's bookkeeping advanced for the skipped work.
+            try:
+                active_idx = self._tick_begin(finished)
+            except IntegrityError as err:
+                self._contain_error(err)
+                active_idx = []
+            try:
+                if active_idx:
+                    pending = self._decode_dispatch(active_idx)
+                    self._decode_collect(active_idx, pending, finished)
+            except IntegrityError as err:
+                self._contain_error(err)
+            try:
+                self._tick_end()
+            except IntegrityError as err:
+                self._contain_error(err)
             return finished
-        # Fault-contained tick: an IntegrityError raised by any phase
-        # (admission reseal/CoW/cache-insert, stale-epoch page-table
-        # checks, the decode MAC gate, the deferred pool check) is
-        # localized and quarantined instead of escaping.  Skipping the
-        # remainder of a phase for one tick is token-invariant: no
-        # slot's bookkeeping advanced for the skipped work.
-        try:
-            active_idx = self._tick_begin(finished)
-        except IntegrityError as err:
-            self._contain_error(err)
-            active_idx = []
-        try:
-            if active_idx:
-                pending = self._decode_dispatch(active_idx)
-                self._decode_collect(active_idx, pending, finished)
-        except IntegrityError as err:
-            self._contain_error(err)
-        try:
-            self._tick_end()
-        except IntegrityError as err:
-            self._contain_error(err)
-        return finished
 
     def _tick_begin(self, finished: list) -> list:
         """Advance the tick: rotation policy, admission, growth.
 
         Returns the slot indices active for this tick's decode."""
         self.tick += 1
-        if (self.registry is not None and self.rotate_every
-                and self.tick % self.rotate_every == 0
-                and self.registry.n_tenants):
-            idx = self._rotate_rr % self.registry.n_tenants
-            self._rotate_rr += 1
-            self.rotate(self.registry.by_index(idx).tenant_id)
-        self._admit(finished)
-        self._ensure_growth()
-        if self.prefix_cache is not None:
-            self._ensure_cow()
-        return [i for i, s in enumerate(self.slots) if s is not None]
+        with self._span("tick_begin"):
+            if (self.registry is not None and self.rotate_every
+                    and self.tick % self.rotate_every == 0
+                    and self.registry.n_tenants):
+                idx = self._rotate_rr % self.registry.n_tenants
+                self._rotate_rr += 1
+                self.rotate(self.registry.by_index(idx).tenant_id)
+            self._admit(finished)
+            self._ensure_growth()
+            if self.prefix_cache is not None:
+                self._ensure_cow()
+            return [i for i, s in enumerate(self.slots) if s is not None]
 
     def _tick_end(self) -> None:
-        if (self.policy.deferred_model_mac and self.defer_interval
-                and self.tick % self.defer_interval == 0):
-            self._deferred_check()
-        # Merkle maintenance shares the deferred cadence but not the
-        # scheme gate: audit proofs exist for every scheme (the page-MAC
-        # table is part of the pool under all of them).
-        if (self.merkle is not None and self.defer_interval
-                and self.tick % self.defer_interval == 0):
-            self._merkle_sync()
+        with self._span("tick_end"):
+            if (self.policy.deferred_model_mac and self.defer_interval
+                    and self.tick % self.defer_interval == 0):
+                with self._span("tick_end.deferred_check"):
+                    self._deferred_check()
+            # Merkle maintenance shares the deferred cadence but not the
+            # scheme gate: audit proofs exist for every scheme (the
+            # page-MAC table is part of the pool under all of them).
+            if (self.merkle is not None and self.defer_interval
+                    and self.tick % self.defer_interval == 0):
+                with self._span("tick_end.merkle"):
+                    self._merkle_sync()
 
     def _merkle_sync(self) -> None:
         roots, leaves = self.merkle.sync()
@@ -1354,6 +1348,7 @@ class SecureServingEngine(SubmitAPI):
         if len(padded) not in self._prefill_shapes:
             self._prefill_shapes.add(len(padded))
             self.stats["prefill_compiles"] += 1
+        self.stats["prefills"] += 1
         return self._prefill_fn(self.params,
                                 jnp.asarray([padded], jnp.int32),
                                 jnp.int32(lp - 1))
@@ -1407,6 +1402,12 @@ class SecureServingEngine(SubmitAPI):
             self._admit_one(req, tenant, finished)
 
     def _admit_one(self, req: Request, tenant, finished: list) -> None:
+        if self.tracer is not None:
+            # Ring buffer only: the wait began at submit (or preemption),
+            # before any profiler annotation could have been opened.
+            self.tracer.add("queue_wait", req.queued_ns,
+                            time.perf_counter_ns(),
+                            {"tick": self.tick, "rid": req.rid})
         seq = req.prompt + req.generated
         if (self.prefix_cache is not None and tenant is not None
                 and req.share_prefix and len(seq) > 1):
@@ -1419,31 +1420,33 @@ class SecureServingEngine(SubmitAPI):
         n_alloc = self._admission_pages(req)
         slot_idx = self.slots.index(None)
         pages = [self.free_pages.pop() for _ in range(n_alloc)]
-        tok, paged_leaves, onchip_leaves = self._prefill(seq)
-        n_write = _ceil_div(len(seq), self.page_tokens)
-        page_ids = np.full((self.pages_per_slot,),
-                           self.spec.scratch_page, np.int32)
-        page_ids[: len(pages)] = pages
-        if tenant is None:
-            self.pool = self._writer(n_write)(
-                self.pool, jnp.asarray(page_ids), paged_leaves,
-                self._next_epoch())
-            page_epochs = []
-        else:
-            epoch = tenant.current_epoch
-            row = self.registry.key_row(tenant.index, epoch)
-            ctx = kvp.PageKeyCtx.make(
-                self._bank(),
-                np.full((self.pages_per_slot,), row, np.int32),
-                np.full((self.pages_per_slot,), tenant.index, np.uint32),
-                np.full((self.pages_per_slot,), epoch, np.uint32))
-            self.pool = self._writer(n_write)(
-                self.pool, jnp.asarray(page_ids), paged_leaves,
-                self._next_epoch(), ctx)
-            page_epochs = [epoch] * len(pages)
-        for j, idx in enumerate(self.onchip_idx):
-            self.onchip[j] = self.onchip[j].at[:, slot_idx].set(
-                onchip_leaves[j][:, 0])
+        with self._span("admit.prefill", rid=req.rid):
+            tok, paged_leaves, onchip_leaves = self._prefill(seq)
+            n_write = _ceil_div(len(seq), self.page_tokens)
+            page_ids = np.full((self.pages_per_slot,),
+                               self.spec.scratch_page, np.int32)
+            page_ids[: len(pages)] = pages
+            if tenant is None:
+                self.pool = self._writer(n_write)(
+                    self.pool, jnp.asarray(page_ids), paged_leaves,
+                    self._next_epoch())
+                page_epochs = []
+            else:
+                epoch = tenant.current_epoch
+                row = self.registry.key_row(tenant.index, epoch)
+                ctx = kvp.PageKeyCtx.make(
+                    self._bank(),
+                    np.full((self.pages_per_slot,), row, np.int32),
+                    np.full((self.pages_per_slot,), tenant.index, np.uint32),
+                    np.full((self.pages_per_slot,), epoch, np.uint32))
+                self.pool = self._writer(n_write)(
+                    self.pool, jnp.asarray(page_ids), paged_leaves,
+                    self._next_epoch(), ctx)
+                page_epochs = [epoch] * len(pages)
+            for j, idx in enumerate(self.onchip_idx):
+                self.onchip[j] = self.onchip[j].at[:, slot_idx].set(
+                    onchip_leaves[j][:, 0])
+            first = int(tok[0, 0])          # syncs on the prefill
         self._admit_seq += 1
         self.stats["admitted"] += 1
         slot = _Slot(req, length=len(seq), pages=pages,
@@ -1453,7 +1456,7 @@ class SecureServingEngine(SubmitAPI):
         self.page_table.install(slot_idx, slot)
         req.state = "running"
         self._note_recovered(req)
-        req.generated.append(int(tok[0, 0]))
+        req.generated.append(first)
         if req.first_tick is None:
             req.first_tick = self.tick
             self._observe_ttft(req)
@@ -1692,6 +1695,7 @@ class SecureServingEngine(SubmitAPI):
         self.slots[idx] = None
         self.page_table.clear(idx)
         slot.req.state = "waiting"
+        slot.req.queued_ns = time.perf_counter_ns()
         slot.req.n_evictions += 1
         self.stats["preemptions"] += 1
         if self._preempt_hook is not None and self._preempt_hook(slot.req):
@@ -1993,94 +1997,102 @@ class SecureServingEngine(SubmitAPI):
         Returns the (still-async) ``(toks, ok)`` device values; the
         pool/onchip state is already swapped to the new (async) arrays.
         """
-        bucket = self.page_table.bucket_for(
-            (self.slots[i].length for i in active_idx), self.page_tokens)
-        page_table = self.page_table.window(bucket)
-        lengths = np.zeros((self.max_slots,), np.int32)
-        active = np.zeros((self.max_slots,), bool)
-        tokens = np.zeros((self.max_slots, 1), np.int32)
-        for i in active_idx:
-            slot = self.slots[i]
-            lengths[i] = slot.length
-            active[i] = True
-            # Replay (shared-prefix hit) teacher-forces the prompt
-            # suffix the skipped prefill still owes the KV cache.
-            tokens[i, 0] = (slot.replay[0] if slot.replay
-                            else slot.req.generated[-1])
-        args = [self.params, self.pool, self.onchip, jnp.asarray(page_table),
-                jnp.asarray(lengths), jnp.asarray(active),
-                jnp.asarray(tokens), self._next_epoch()]
-        uniform = False
-        if self.registry is not None:
-            tenant_args, uniform = self._tenant_decode_args(active_idx,
-                                                            bucket)
-            args += tenant_args
-        decode_fn = self._decode_fn_for(bucket, uniform)
-        if uniform or self.registry is None:
-            # Single-key tick: flat crypt/MAC route, fused kernels when
-            # the spec qualifies.
-            self.stats["uniform_fast_ticks"] += 1
-        elif kvp._kernel_read_ok(self.spec) and \
-                self.spec.cfg.verify != "none":
-            # Mixed bank rows, but the fused kernel stays on via its
-            # per-page round-key gather.  (verify == "none" reads skip
-            # MACs entirely and never enter the fused kernel, so they
-            # must not count as fused ticks.)
-            self.stats["fused_mixed_ticks"] += 1
-        if self.spec.cfg.verify != "none":
-            # Which implementation verified this tick's page read: the
-            # fused kernel, or the jnp reference (wide-block schemes,
-            # or kernels off).
-            if kvp._kernel_read_ok(self.spec):
-                self.stats["fused_read_ticks"] += 1
-            else:
-                self.stats["reference_read_ticks"] += 1
-        if kvp._kernel_write_ok(self.spec) and \
-                self.spec.cfg.verify != "none":
-            # The tick's dirty-page reseal runs the one-pass fused
-            # write kernel (single-key, uniform, or mixed-row alike) —
-            # write_pages never touches the vmapped reference.
-            self.stats["fused_write_ticks"] += 1
-        self.stats["decode_page_reads"] += len(active_idx) * bucket
-        self._bucket_hist.observe(bucket)
-        self.pool, self.onchip, toks, ok = decode_fn(*args)
-        self.stats["decode_steps"] += 1
-        return toks, ok
+        with self._span("decode_dispatch"):
+            bucket = self.page_table.bucket_for(
+                (self.slots[i].length for i in active_idx), self.page_tokens)
+            page_table = self.page_table.window(bucket)
+            lengths = np.zeros((self.max_slots,), np.int32)
+            active = np.zeros((self.max_slots,), bool)
+            tokens = np.zeros((self.max_slots, 1), np.int32)
+            for i in active_idx:
+                slot = self.slots[i]
+                lengths[i] = slot.length
+                active[i] = True
+                # Replay (shared-prefix hit) teacher-forces the prompt
+                # suffix the skipped prefill still owes the KV cache.
+                tokens[i, 0] = (slot.replay[0] if slot.replay
+                                else slot.req.generated[-1])
+            args = [self.params, self.pool, self.onchip,
+                    jnp.asarray(page_table), jnp.asarray(lengths),
+                    jnp.asarray(active), jnp.asarray(tokens),
+                    self._next_epoch()]
+            uniform = False
+            if self.registry is not None:
+                tenant_args, uniform = self._tenant_decode_args(active_idx,
+                                                                bucket)
+                args += tenant_args
+            decode_fn = self._decode_fn_for(bucket, uniform)
+            if uniform or self.registry is None:
+                # Single-key tick: flat crypt/MAC route, fused kernels when
+                # the spec qualifies.
+                self.stats["uniform_fast_ticks"] += 1
+            elif kvp._kernel_read_ok(self.spec) and \
+                    self.spec.cfg.verify != "none":
+                # Mixed bank rows, but the fused kernel stays on via its
+                # per-page round-key gather.  (verify == "none" reads skip
+                # MACs entirely and never enter the fused kernel, so they
+                # must not count as fused ticks.)
+                self.stats["fused_mixed_ticks"] += 1
+            if self.spec.cfg.verify != "none":
+                # Which implementation verified this tick's page read: the
+                # fused kernel, or the jnp reference (wide-block schemes,
+                # or kernels off).
+                if kvp._kernel_read_ok(self.spec):
+                    self.stats["fused_read_ticks"] += 1
+                else:
+                    self.stats["reference_read_ticks"] += 1
+            if kvp._kernel_write_ok(self.spec) and \
+                    self.spec.cfg.verify != "none":
+                # The tick's dirty-page reseal runs the one-pass fused
+                # write kernel (single-key, uniform, or mixed-row alike) —
+                # write_pages never touches the vmapped reference.
+                self.stats["fused_write_ticks"] += 1
+            self.stats["decode_page_reads"] += len(active_idx) * bucket
+            self._bucket_hist.observe(bucket)
+            self.pool, self.onchip, toks, ok = decode_fn(*args)
+            self.stats["decode_steps"] += 1
+            return toks, ok
 
     def _decode_collect(self, active_idx: list, pending,
                         finished: list) -> None:
         """Sync on a dispatched decode and apply host bookkeeping."""
-        toks, ok = pending
-        if self.verify_every_step:
-            if not self.page_io.report_verdict(ok, "decode"):
-                self._decode_failure(active_idx)
-        else:
-            self._ok_accum = self._ok_accum & ok
-        toks = np.asarray(toks)
-        for i in active_idx:
-            slot = self.slots[i]
-            if slot is None:
-                continue    # quarantined + preempted by _decode_failure:
-                            # its bookkeeping must not advance — recompute
-                            # recovery replays from the last good token.
-            if slot.tenant is not None:
-                # The dirty page was just re-encrypted under the
-                # tenant's CURRENT epoch (lazy rotation lands here).
-                dirty = slot.length // self.page_tokens
-                if dirty < len(slot.page_epochs):
-                    slot.page_epochs[dirty] = slot.tenant.current_epoch
-            slot.length += 1
-            if slot.replay:
-                slot.replay.popleft()
+        with self._span("decode_collect"):
+            toks, ok = pending
+            with self._span("decode.wait"):
+                if self.verify_every_step:
+                    ok = bool(ok)
+                toks = np.asarray(toks)
+            if self.verify_every_step:
+                if not self.page_io.report_verdict(ok, "decode"):
+                    self._decode_failure(active_idx)
+            else:
+                self._ok_accum = self._ok_accum & ok
+            for i in active_idx:
+                slot = self.slots[i]
+                if slot is None:
+                    # Quarantined + preempted by _decode_failure: its
+                    # bookkeeping must not advance — recompute recovery
+                    # replays from the last good token.
+                    continue
+                if slot.tenant is not None:
+                    # The dirty page was just re-encrypted under the
+                    # tenant's CURRENT epoch (lazy rotation lands here).
+                    dirty = slot.length // self.page_tokens
+                    if dirty < len(slot.page_epochs):
+                        slot.page_epochs[dirty] = slot.tenant.current_epoch
+                slot.length += 1
                 if slot.replay:
-                    continue        # mid-replay: the sample is discarded
-                # The LAST replay step's sample is the first real output
-                # (exactly what a full prefill would have returned).
-            slot.req.generated.append(int(toks[i, 0]))
-            if slot.req.first_tick is None:
-                slot.req.first_tick = self.tick
-                self._observe_ttft(slot.req)
-            self._maybe_finish(i, finished)
+                    slot.replay.popleft()
+                    if slot.replay:
+                        continue    # mid-replay: the sample is discarded
+                    # The LAST replay step's sample is the first real
+                    # output (exactly what a full prefill would have
+                    # returned).
+                slot.req.generated.append(int(toks[i, 0]))
+                if slot.req.first_tick is None:
+                    slot.req.first_tick = self.tick
+                    self._observe_ttft(slot.req)
+                self._maybe_finish(i, finished)
 
     def _decode_failure(self, active_idx: list) -> None:
         """The decode-tick MAC gate failed: localize, then contain.

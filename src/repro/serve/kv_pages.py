@@ -903,33 +903,41 @@ class PageIO:
         ok = jnp.asarray(True)
         agg = jnp.zeros((s, p, mac.MAC_BYTES), jnp.uint8)
         dense = []
+        # Named scopes split the read in the compiled HLO's op_name
+        # metadata: the crossing (decrypt + verify) and the dense view.
         for li, leaf in enumerate(spec.leaves):
             ct = pool.cts[li][flat_ids].reshape(s, p, leaf.page_bytes)
             need_macs = cfg.verify != "none"
             if need_macs and _kernel_read_ok(spec):
-                pt, page_macs = _fused_read(spec, leaf,
-                                            ct.reshape(-1, leaf.page_bytes),
-                                            flat_ids, vns, keys, ctx, uniform)
-                dense.append(_pages_to_dense(
-                    spec, leaf, pt.reshape(s, p, -1), lengths))
-                agg = agg ^ page_macs.reshape(s, p, mac.MAC_BYTES)
+                with jax.named_scope("crossing"):
+                    pt, page_macs = _fused_read(
+                        spec, leaf, ct.reshape(-1, leaf.page_bytes),
+                        flat_ids, vns, keys, ctx, uniform)
+                    agg = agg ^ page_macs.reshape(s, p, mac.MAC_BYTES)
+                with jax.named_scope("dense"):
+                    dense.append(_pages_to_dense(
+                        spec, leaf, pt.reshape(s, p, -1), lengths))
                 continue
-            pt = _crypt(spec, leaf, ct.reshape(-1, leaf.page_bytes),
+            with jax.named_scope("crossing"):
+                pt = _crypt(spec, leaf, ct.reshape(-1, leaf.page_bytes),
+                            flat_ids, vns, keys, ctx,
+                            uniform).reshape(s, p, leaf.page_bytes)
+                macs = None
+                if need_macs:
+                    macs = _page_block_macs(
+                        spec, leaf, ct.reshape(-1, leaf.page_bytes),
                         flat_ids, vns, keys, ctx,
-                        uniform).reshape(s, p, leaf.page_bytes)
-            macs = None
-            if need_macs:
-                macs = _page_block_macs(
-                    spec, leaf, ct.reshape(-1, leaf.page_bytes), flat_ids,
-                    vns, keys, ctx, uniform).reshape(s, p, leaf.n_blocks,
-                                                     mac.MAC_BYTES)
-            if cfg.verify == "block":
-                stored = pool.block_macs[li][flat_ids].reshape(macs.shape)
-                ok = ok & jnp.all((macs == stored) | ~touched[..., None, None])
-            elif cfg.verify == "layer":
-                agg = agg ^ mac.xor_aggregate(macs, axis=2)
-            dense.append(_pages_to_dense(spec, leaf, _page_values(leaf, pt),
-                                         lengths))
+                        uniform).reshape(s, p, leaf.n_blocks, mac.MAC_BYTES)
+                if cfg.verify == "block":
+                    stored = pool.block_macs[li][flat_ids].reshape(
+                        macs.shape)
+                    ok = ok & jnp.all((macs == stored)
+                                      | ~touched[..., None, None])
+                elif cfg.verify == "layer":
+                    agg = agg ^ mac.xor_aggregate(macs, axis=2)
+            with jax.named_scope("dense"):
+                dense.append(_pages_to_dense(
+                    spec, leaf, _page_values(leaf, pt), lengths))
         if cfg.verify == "layer":
             stored = pool.page_macs[flat_ids].reshape(s, p, mac.MAC_BYTES)
             ok = ok & jnp.all((agg == stored) | ~touched[..., None])

@@ -7,7 +7,9 @@ Covers the telemetry contracts:
   * histograms — ``percentile()`` matches numpy's default linear
     interpolation;
   * tracer — exports valid Chrome trace-event JSON with tick-phase
-    spans correctly nested inside their tick span;
+    spans correctly nested inside their tick span; each span is also a
+    profiler annotation in the ``.xplane.pb``; the engine's spans count
+    what its counters count; an untraced engine opens none;
   * audit log — the SHA-256 chain verifies end-to-end and any
     single-field tamper, truncation, or reorder breaks it;
   * engines — tracing + metrics + audit enabled is observation-only
@@ -15,6 +17,8 @@ Covers the telemetry contracts:
     counters up with per-shard labels.
 """
 
+import collections
+import glob
 import json
 import re
 
@@ -29,7 +33,7 @@ from repro.models.layers import init_params
 from repro.obs.audit import AuditLog
 from repro.obs.metrics import (ENGINE_COUNTERS, Histogram, MetricsRegistry,
                                StatsView)
-from repro.obs.trace import SpanTracer
+from repro.obs.trace import NO_SPAN, SpanTracer
 from repro.serve.cluster import ClusterEngine
 from repro.serve.engine import SecureServingEngine
 from repro.tenancy import KeyHierarchy, TenantRegistry
@@ -55,6 +59,34 @@ def _engine(smoke, **kw):
     kw.setdefault("page_tokens", 4)
     kw.setdefault("pages_per_slot", 4)
     return SecureServingEngine(arch, cfg, params, **kw)
+
+
+def _count_annotations(mp, opened: list) -> None:
+    """Record the name of every profiler annotation opened through
+    ``jax.profiler`` while ``mp`` (a MonkeyPatch) is active."""
+    for cls in ("TraceAnnotation", "StepTraceAnnotation"):
+        base = getattr(jax.profiler, cls)
+
+        class Counted(base):
+            def __init__(self, name, **kw):
+                opened.append(name)
+                super().__init__(name, **kw)
+        mp.setattr(jax.profiler, cls, Counted)
+
+
+@pytest.fixture(scope="module")
+def traced_run(smoke, prompts):
+    """A traced engine that served three requests through two slots
+    (so one waited in the queue), and the profiler annotations its
+    spans opened."""
+    opened: list = []
+    with pytest.MonkeyPatch.context() as mp:
+        _count_annotations(mp, opened)
+        eng = _engine(smoke, scheme="seda", trace=True)
+        for p in prompts:
+            eng.submit(prompt=p, max_new_tokens=4)
+        eng.run()
+    return eng, opened
 
 
 class TestRegistry:
@@ -218,11 +250,8 @@ class TestTrace:
         assert len(events) == 8
         assert events[0]["name"] == "s12"
 
-    def test_phase_spans_nested(self, smoke, prompts):
-        eng = _engine(smoke, scheme="seda", trace=True)
-        for p in prompts[:2]:
-            eng.submit(p, max_new_tokens=4)
-        eng.run()
+    def test_phase_spans_nested(self, traced_run):
+        eng, _ = traced_run
         events = eng.tracer.events()
         ticks = [e for e in events if e["name"] == "tick"]
         phases = [e for e in events if e["name"].startswith(
@@ -235,6 +264,67 @@ class TestTrace:
             assert any(t["ts"] - 1e-6 <= ph["ts"] and
                        ph["ts"] + ph["dur"] <= t["ts"] + t["dur"] + 1e-6
                        for t in ticks), ph["name"]
+
+
+    def test_span_lands_in_profiler_trace(self, tmp_path):
+        from jax.profiler import ProfileData
+        tr = SpanTracer()
+        jax.profiler.start_trace(str(tmp_path))
+        with tr.span("tick", step_num=1, tick=1):
+            with tr.span("decode.wait"):
+                jax.numpy.ones(4).block_until_ready()
+        jax.profiler.stop_trace()
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        host = {ev.name for p in ProfileData.from_file(path).planes
+                if p.name.startswith("/host:")
+                for line in p.lines for ev in line.events}
+        assert {"engine.tick", "engine.decode.wait"} <= host
+        assert [e["name"] for e in tr.events()] == ["decode.wait", "tick"]
+
+    def test_engine_spans_match_counters(self, traced_run):
+        eng, opened = traced_run
+        events = eng.tracer.events()
+        names = collections.Counter(e["name"] for e in events)
+        stats = eng.stats
+        assert names["admit.prefill"] == stats["prefills"] == 3
+        assert names["decode.wait"] == stats["decode_steps"] > 0
+        assert names["queue_wait"] == stats["admitted"] == 3
+        assert names["tick"] == eng.tick
+        waits = sorted(e["dur"] for e in events if e["name"] == "queue_wait")
+        assert waits[-1] > 0           # the third request waited a slot
+        assert {e["args"]["rid"] for e in events
+                if e["name"] == "admit.prefill"} == {0, 1, 2}
+        # Every span but the backdated queue wait is a profiler
+        # annotation, named engine.<span>.
+        assert collections.Counter(opened) == collections.Counter(
+            "engine." + n for n in names.elements() if n != "queue_wait")
+
+    def test_untraced_engine_opens_no_span(self, smoke, prompts,
+                                           monkeypatch):
+        opened, added = [], []
+        _count_annotations(monkeypatch, opened)
+        monkeypatch.setattr(SpanTracer, "add",
+                            lambda self, *a, **kw: added.append(a))
+        eng = _engine(smoke, scheme="seda")
+        assert eng._span("tick") is NO_SPAN
+        eng.submit(prompt=prompts[0], max_new_tokens=3)
+        eng.run()
+        assert eng.tick > 1 and eng.tracer is None
+        assert opened == [] and added == []
+
+    def test_decode_program_scopes(self, smoke):
+        """The decode program keeps its name and carries a named scope
+        for each of its layers in the compiled HLO's op_name."""
+        eng = _engine(smoke, scheme="seda")
+        text = eng._decode_fn_for(4).lower(
+            *eng._decode_analysis_args(4)).compile().as_text()
+        assert re.match(r"HloModule jit_decode_fn\b", text)
+        ops = set(re.findall(r'op_name="([^"]*)"', text))
+        for scope in ("pageio.read/crossing", "pageio.read/dense",
+                      "model.attention", "model.ffn", "model.head",
+                      "sample", "pageio.write"):
+            assert any(scope in op for op in ops), scope
 
 
 class TestAudit:
@@ -372,6 +462,8 @@ class TestEngineObs:
             by_pid_phase.setdefault((e["pid"], e["name"]), []).append(e)
         assert len(by_pid_phase) > 1
         for (pid, name), spans in by_pid_phase.items():
+            if name == "queue_wait":
+                continue    # requests wait side by side, so waits overlap
             spans.sort(key=lambda e: e["ts"])
             for prev, nxt in zip(spans, spans[1:]):
                 assert prev["ts"] + prev["dur"] <= nxt["ts"] + 1e-6, \
